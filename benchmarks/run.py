@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import subprocess
 import sys
 import time
@@ -94,7 +95,10 @@ def main(argv=None) -> None:
 
     from benchmarks import harness as H
     from benchmarks import paper_benchmarks as P
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.obs.profile import maybe_trace
+    enable_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     H.set_backend(args.backend)
     names = list(P.ALL) if not args.only else args.only.split(",")
     rows = []

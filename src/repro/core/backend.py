@@ -16,19 +16,15 @@ invariant; see tests/test_hlo_budget.py) and zero HLO drift.
 everywhere) auto-resolves from the runtime platform: interpret on CPU,
 compiled on GPU/TPU — so a TPU caller that just flips
 ``backend="pallas"`` gets real kernels, not a silent interpreter run.
-Forcing ``interpret=True`` on an accelerator warns once.
+Forcing ``interpret=True`` on an accelerator is an error.
 """
 from __future__ import annotations
-
-import warnings
 
 import jax
 
 REFERENCE = "reference"
 PALLAS = "pallas"
 BACKENDS = (REFERENCE, PALLAS)
-
-_warned_forced_interpret = False
 
 
 def check(backend: str) -> str:
@@ -45,20 +41,16 @@ def resolve_interpret(interpret: bool | None,
 
     ``None`` -> interpret only when the runtime platform is CPU (the
     interpreter is the only way to run these kernels there; on GPU/TPU
-    the compiled kernel is the point).  ``True`` on an accelerator is
-    honored but warns once — it silently discards the hardware.
+    the compiled kernel is the point).  ``True`` on an accelerator
+    raises: it would discard the hardware it runs on.
     """
     if platform is None:
         platform = jax.default_backend()
     if interpret is None:
         return platform == "cpu"
     if interpret and platform != "cpu":
-        global _warned_forced_interpret
-        if not _warned_forced_interpret:
-            _warned_forced_interpret = True
-            warnings.warn(
-                f"interpret=True forced on platform {platform!r}: Pallas "
-                "kernels will run in the interpreter, not on the "
-                "accelerator (pass interpret=None to auto-resolve)",
-                stacklevel=2)
+        raise ValueError(
+            f"interpret=True on platform {platform!r} would run the Pallas "
+            "kernels in the interpreter, not on the accelerator (pass "
+            "interpret=None to auto-resolve)")
     return bool(interpret)

@@ -27,7 +27,8 @@ from repro.core import bloom, mapper, msc, tracker
 from repro.core.tiers import (Counters, TierConfig, TierState, bucket_of,
                               fast_occupancy, run_of_keys)
 from repro.core.utils import (PADKEY, alloc_slots, merge_index_update,
-                              segment_in_range, sorted_lookup)
+                              segment_in_range, sorted_lookup,
+                              splice_index_range)
 
 
 class Movement(NamedTuple):
@@ -69,6 +70,28 @@ class CompactionStats(NamedTuple):
     n_run_written: jax.Array   # slow objects written (new runs, seq I/O)
 
 
+def sub_runs(mvalid: jax.Array, n_merged: jax.Array,
+             run_size: int) -> tuple[jax.Array, int]:
+    """Split a merge's sorted output into sub-runs of [run_size,
+    2*run_size) rows (one smaller run when fewer than run_size rows
+    merged).  Returns ``(sub_of, n_sub)``: the sub-run of each row
+    (``n_sub - 1`` on invalid rows) and the static sub-run count.
+
+    The remainder joins the last full sub-run instead of becoming a run
+    of its own: a split into full runs plus a small tail leaves behind
+    runs whose ownership range no later write lands in, which pile up
+    until the run directory is full -- and a merge that finds no free
+    directory entry loses rows.  Every run stays below 2*run_size, the
+    per-run share of each window cap, so a later window reads it whole.
+    """
+    n_sub = max(mvalid.shape[0] // run_size, 1) + 1
+    rank = jnp.cumsum(mvalid.astype(jnp.int32)) - 1       # rank among valid
+    n_full = jnp.maximum(n_merged // run_size, 1)
+    sub_of = jnp.where(mvalid, jnp.minimum(rank // run_size, n_full - 1),
+                       n_sub - 1).astype(jnp.int32)
+    return sub_of, n_sub
+
+
 def compact_once(state: TierState, cfg: TierConfig, rng: jax.Array,
                  promote: bool = True, precise: bool = False,
                  cap_fast: int | None = None,
@@ -108,6 +131,15 @@ def compact_once(state: TierState, cfg: TierConfig, rng: jax.Array,
                                           interpret=interpret)
     lo, hi = cand.lo[best], cand.hi[best]
     run_start, run_span = cand.run_start[best], cand.run_span[best]
+    # A window reads at most cap_slow slow rows.  Runs stay below that
+    # (``sub_runs``), but rows a merge wrote when the run directory had no
+    # free entry can push a key range past it: the window then ends at the
+    # first row it cannot read, so that the merged rows splice back into
+    # the slow index in order.
+    s_lo = jnp.searchsorted(state.sidx_keys, lo)
+    over = jnp.searchsorted(state.sidx_keys, hi) - s_lo > cap_slow
+    hi = jnp.where(over, state.sidx_keys[jnp.minimum(
+        s_lo + cap_slow, state.sidx_keys.shape[0] - 1)], hi)
 
     hist = tracker.clock_histogram(state.tracker)
     # capacity guard (beyond-paper; the paper defers threshold tuning to
@@ -188,9 +220,9 @@ def compact_once(state: TierState, cfg: TierConfig, rng: jax.Array,
                                              mode="drop")
     fast_ver = fast_ver.at[ptgt].set(1, mode="drop")
     # incremental index maintenance: drop the demoted slots, merge in the
-    # promotions -- O(pool) movement, no full re-sort
+    # promotions
     dropf = jnp.zeros((nf,), bool).at[
-        jnp.where(demote, fslots, nf)].set(True, mode="drop")
+        jnp.where(demote, fpos, nf)].set(True, mode="drop")
     fidx_keys, fidx_slots = merge_index_update(
         state.fidx_keys, state.fidx_slots, dropf, skeys, pro_slots, pro_ok)
 
@@ -232,16 +264,21 @@ def compact_once(state: TierState, cfg: TierConfig, rng: jax.Array,
         (run_start >= 0) & (jnp.arange(cfg.range_fanout_i) < run_span),
         order_runs[jnp.clip(win_pos, 0, r - 1)], r).astype(jnp.int32)
 
-    in_window = jnp.any(state.slow_run[:, None] == win_rids[None, :], axis=1)
+    # the rows the window read leave the pool: the window runs' rows, and
+    # any row of [lo, hi) that a merge wrote when the run directory had no
+    # free entry (its run id is max_runs).  Freeing by run id alone would
+    # leave such rows behind in the middle of the index range the splice
+    # below replaces.
+    ns = state.slow_keys.shape[0]
+    in_window = jnp.zeros((ns,), bool).at[
+        jnp.where(sm, sslots, ns)].set(True, mode="drop")
     slow_keys = jnp.where(in_window, -1, state.slow_keys)
     slow_run = jnp.where(in_window, -1, state.slow_run)
 
-    # ---- write the merged output as sub-runs of <= run_size --------------
-    # (the paper writes "new SST file(s)": splitting keeps run sizes bounded)
-    m_total = mkeys.shape[0]
-    n_sub = max(m_total // cfg.run_size, 1) + 1
-    rank = jnp.cumsum(mvalid.astype(jnp.int32)) - 1          # rank among valid
-    sub_of = jnp.where(mvalid, rank // cfg.run_size, n_sub - 1).astype(jnp.int32)
+    # ---- write the merged output as sub-runs ----------------------------
+    # (the paper writes "new SST file(s)": splitting keeps run sizes
+    # bounded)
+    sub_of, n_sub = sub_runs(mvalid, n_merged, cfg.run_size)
 
     new_slots = alloc_slots(slow_keys, mvalid)
     wrote = mvalid & (new_slots >= 0)
@@ -261,11 +298,14 @@ def compact_once(state: TierState, cfg: TierConfig, rng: jax.Array,
         .astype(jnp.int32)
     slow_run = slow_run.at[stgt].set(free_rids[jnp.clip(sub_of, 0, n_sub - 1)],
                                      mode="drop")
-    # slow index: the freed runs' slots drop out, the merged writes merge
-    # in (runs hold disjoint key ranges, so merged keys are fresh)
-    sidx_keys, sidx_slots = merge_index_update(
-        state.sidx_keys, state.sidx_slots, in_window, mkeys, new_slots,
-        wrote)
+    # slow index: the window read one contiguous stretch of the index
+    # (every slow key of [lo, hi)); the merged writes (a sorted prefix of
+    # mkeys, all in [lo, hi)) take its place
+    sidx_keys, sidx_slots = splice_index_range(
+        state.sidx_keys, state.sidx_slots,
+        jnp.searchsorted(state.sidx_keys, lo).astype(jnp.int32),
+        jnp.sum(sm.astype(jnp.int32)), mkeys, new_slots,
+        jnp.sum(wrote.astype(jnp.int32)), cap_slow)
 
     # per-sub-run counts and key bounds
     sub_counts = jnp.zeros((n_sub,), jnp.int32).at[sub_of].add(
@@ -655,9 +695,9 @@ def compact_boundary(state: TierState, cfg: TierConfig, boundary: int, *,
       2. drops lower copies superseded by the migrating run, drops
          tombstone rows whose key is bloom-negative in every deeper
          tier, carries the rest of the tombstones down;
-      3. merge-sorts the survivors into fresh lower-tier sub-runs of
-         <= ``run_size`` (new Blooms, directory entries, incremental
-         index maintenance on BOTH tiers -- no pool-sized re-sorts).
+      3. merge-sorts the survivors into fresh lower-tier sub-runs
+         (``sub_runs``; new Blooms, directory entries, incremental
+         index maintenance on BOTH tiers).
 
     Counters: both windows land in per-tier ``reads``/``comp_reads``,
     the output in ``writes[boundary+1]``, and the job increments
@@ -669,14 +709,15 @@ def compact_boundary(state: TierState, cfg: TierConfig, boundary: int, *,
     assert boundary >= 1, "boundary 0 is compact_once's slab merge"
     u, l = boundary, boundary + 1
     du, dl = u - 1, l - 1
-    # upper window = ONE run, and runs are written as sub-runs of
-    # <= run_size everywhere, so 2x is already an upper bound.  The lower
-    # window is every overlapped run: a wide upper run can overlap ALL of
-    # them, and truncating the window while freeing the sources wholesale
-    # would lose rows -- cap it at the exact static bound instead.
+    # upper window = ONE run, and runs are written as sub-runs of fewer
+    # than 2*run_size rows everywhere (``sub_runs``), so 2x is an upper
+    # bound.  The lower window is every overlapped run: a wide upper run
+    # can overlap ALL of them, and truncating the window while freeing
+    # the sources wholesale would lose rows -- cap it at the static bound
+    # instead.
     cap_up = cap_up or 2 * cfg.run_size
     cap_lo = cap_lo or min(cfg.tier_sizes[l],
-                           cfg.max_runs * cfg.run_size)
+                           2 * cfg.max_runs * cfg.run_size)
     r = cfg.max_runs
     nl = state.keys[l].shape[0]
 
@@ -716,7 +757,7 @@ def compact_boundary(state: TierState, cfg: TierConfig, boundary: int, *,
     ukeep = um & (~utomb | keep_ut)
     lkeep = lm & ~superseded & (~ltomb | keep_lt)
 
-    # ---- merge-sort into <= run_size sub-runs ---------------------------
+    # ---- merge-sort ------------------------------------------------------
     mkeys = jnp.concatenate([jnp.where(ukeep, ukeys, PADKEY),
                              jnp.where(lkeep, lkeys, PADKEY)])
     mvals = jnp.concatenate([state.vals[u][uslots],
@@ -732,7 +773,8 @@ def compact_boundary(state: TierState, cfg: TierConfig, boundary: int, *,
     up_keys = jnp.where(in_up_win, -1, state.keys[u])
     up_runs = jnp.where(in_up_win, -1, state.runs[du])
     uidx_keys, uidx_slots = merge_index_update(
-        state.idx_keys[u], state.idx_slots[u], in_up_win,
+        state.idx_keys[u], state.idx_slots[u],
+        in_up_win[state.idx_slots[u]] & (state.idx_keys[u] != PADKEY),
         jnp.full((1,), PADKEY, jnp.int32), jnp.full((1,), -1, jnp.int32),
         jnp.zeros((1,), bool))
     udir_act = state.dir_active[du].at[rid].set(False)
@@ -744,11 +786,7 @@ def compact_boundary(state: TierState, cfg: TierConfig, boundary: int, *,
     lo_runs = jnp.where(in_lo_win, -1, lrun)
 
     # ---- write merged output into the lower tier ------------------------
-    m_total = mkeys.shape[0]
-    n_sub = max(m_total // cfg.run_size, 1) + 1
-    rank = jnp.cumsum(mvalid.astype(jnp.int32)) - 1
-    sub_of = jnp.where(mvalid, rank // cfg.run_size,
-                       n_sub - 1).astype(jnp.int32)
+    sub_of, n_sub = sub_runs(mvalid, n_merged, cfg.run_size)
     new_slots = alloc_slots(lo_keys, mvalid)
     wrote = mvalid & (new_slots >= 0)
     stgt = jnp.where(wrote, new_slots, nl)
@@ -765,8 +803,9 @@ def compact_boundary(state: TierState, cfg: TierConfig, boundary: int, *,
     lo_runs = lo_runs.at[stgt].set(
         free_rids[jnp.clip(sub_of, 0, n_sub - 1)], mode="drop")
     lidx_keys, lidx_slots = merge_index_update(
-        state.idx_keys[l], state.idx_slots[l], in_lo_win, mkeys,
-        new_slots, wrote)
+        state.idx_keys[l], state.idx_slots[l],
+        in_lo_win[state.idx_slots[l]] & (state.idx_keys[l] != PADKEY),
+        mkeys, new_slots, wrote)
 
     sub_counts = jnp.zeros((n_sub,), jnp.int32).at[sub_of].add(
         wrote.astype(jnp.int32))
@@ -784,15 +823,15 @@ def compact_boundary(state: TierState, cfg: TierConfig, boundary: int, *,
     ldir_cnt = ldir_cnt.at[dir_tgt].set(sub_counts, mode="drop")
     # fori_loop, not a static unroll: n_sub scales with the (pool-sized)
     # lower window cap, and valid rows form a contiguous sorted prefix,
-    # so sub-run j's rows are exactly positions [j*run_size, (j+1)*
-    # run_size) -- a dynamic_slice keeps each bloom build run-sized.
-    # dynamic_slice clamps the tail start, which can only ADD foreign
-    # keys to the last row (bloom false positives: safe).
+    # so sub-run j's rows start at position j*run_size and number fewer
+    # than 2*run_size -- a dynamic_slice keeps each bloom build run-sized
+    # (the mask drops the next sub-run's rows and the clamped tail's).
+    span = min(2 * cfg.run_size, mkeys.shape[0])
+
     def _bloom_body(j, bl):
-        ks = lax.dynamic_slice(mkeys, (j * cfg.run_size,),
-                               (cfg.run_size,))
-        vm = lax.dynamic_slice(wrote, (j * cfg.run_size,),
-                               (cfg.run_size,))
+        ks = lax.dynamic_slice(mkeys, (j * cfg.run_size,), (span,))
+        vm = lax.dynamic_slice(wrote & (sub_of == j), (j * cfg.run_size,),
+                               (span,))
         return lax.cond(
             sub_ok[j],
             lambda b: bloom.set_run(b, free_rids[j], ks, vm),

@@ -277,6 +277,25 @@ def resolve_mesh(mesh, n_partitions: int):
     return mesh
 
 
+def _init_on_mesh(init, rngs, mesh, lp: int):
+    """Build each device's ``lp`` partitions ON that device, then assemble
+    the ``part``-sharded global state: no device ever holds more than
+    its own partitions' pools (a deployment-sized partition fills a good
+    share of one chip's memory)."""
+    from repro.distributed import sharding as shd
+    devs = list(mesh.devices.reshape(-1))
+    local = []
+    for i, dev in enumerate(devs):
+        with jax.default_device(dev):
+            local.append(init(jax.device_put(rngs[i * lp:(i + 1) * lp],
+                                             dev)))
+    shardings = shd.leading_axis_sharding(jax.eval_shape(init, rngs), mesh)
+    return jax.tree.map(
+        lambda sh, *xs: jax.make_array_from_single_device_arrays(
+            (xs[0].shape[0] * len(devs),) + xs[0].shape[1:], sh, list(xs)),
+        shardings, *local)
+
+
 class PartitionedDB:
     """Shared-nothing partitions (paper §4.1, Fig. 11d): vmap on one
     device, ``shard_map`` over a device mesh when one is available.
@@ -319,16 +338,14 @@ class PartitionedDB:
             compaction_quantum=compaction_quantum,
             mesh_axis=PART_AXIS if self.mesh is not None else None)
         rngs = jax.random.split(jax.random.PRNGKey(seed), n_partitions)
-        self.estate = jax.vmap(
-            functools.partial(engine.init, self.ecfg))(rngs)
+        # one program per device: no pool is built twice (engine.init)
+        init = jax.jit(jax.vmap(functools.partial(engine.init, self.ecfg)))
         self._dropped = jnp.zeros((n_partitions,), jnp.int32)
         if self.mesh is not None:
-            from repro.distributed import sharding as shd
-            self._shardings = shd.leading_axis_sharding(self.estate,
-                                                        self.mesh)
-            self.estate = jax.device_put(self.estate, self._shardings)
+            self.estate = _init_on_mesh(init, rngs, self.mesh, self.lp)
             self._mesh_steps = {}
         else:
+            self.estate = init(rngs)
             self._step = jax.jit(
                 functools.partial(_partitioned_step, cfg=self.ecfg,
                                   p=n_partitions),
@@ -357,7 +374,6 @@ class PartitionedDB:
         device count, shard it, exchange device-side, step.  The
         (padded-width, capacity) pair keys a small jit cache -- client
         batch sizes are few and static in practice."""
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         d = self.mesh.shape[PART_AXIS]
         b = keys.shape[0]
@@ -369,11 +385,11 @@ class PartitionedDB:
         if fn is None:
             local = functools.partial(_mesh_step, cfg=self.ecfg, p=self.p,
                                       lp=self.lp, cap=cap)
-            sm = shard_map(
+            sm = jax.shard_map(
                 local, mesh=self.mesh,
                 in_specs=(P(PART_AXIS), P(PART_AXIS), P(PART_AXIS), P()),
                 out_specs=(P(PART_AXIS), P(PART_AXIS), P()),
-                check_rep=False)
+                check_vma=False)
             fn = jax.jit(sm, donate_argnums=(0,))
             self._mesh_steps[(bpad, cap)] = fn
         kpad = jnp.zeros((bpad,), jnp.int32).at[:b].set(keys)

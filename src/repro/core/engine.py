@@ -160,17 +160,24 @@ def dealias(tree):
         lambda x: jnp.array(x) if isinstance(x, jax.Array) else x, tree)
 
 
+# One program builds a fresh tier state, so each leaf lands in a buffer
+# of its own on the device.  Built op by op and then copied (``dealias``),
+# every pool would be held twice at once.
+_init_tier = jax.jit(tiers.init, static_argnums=0)
+
+
 def init(cfg: EngineConfig, rng: jax.Array, payload: Any = (),
          tier: TierState | None = None) -> EngineState:
     backend_mod.check(cfg.backend)
-    return dealias(EngineState(
-        tier=tier if tier is not None else tiers.init(cfg.tier),
-        pol=policy.init(), rng=rng,
+    rest = dealias(EngineState(
+        tier=tier, pol=policy.init(), rng=rng,
         virtual_extra=jnp.zeros((), jnp.int32),
         steps=jnp.zeros((), jnp.int32), payload=payload,
         obs=obs_plane.init(cfg.obs) if cfg.obs.enabled else (),
         comp=(compaction.init_inflight(cfg.tier)
               if cfg.compaction_quantum > 0 else ())))
+    return rest if tier is not None else rest._replace(
+        tier=_init_tier(cfg.tier))
 
 
 def make_op(kind: int, keys: jax.Array, vals: jax.Array | None = None,

@@ -43,15 +43,19 @@ class Candidate(NamedTuple):
 def bucket_clock_hist(state: TierState, cfg: TierConfig) -> jax.Array:
     """int32[B, 4]: clock histogram of *tracked fast-tier* keys per bucket.
 
-    Recomputed per compaction round (O(T) bincount) -- the approx-MSC
-    benefit/popularity estimate reads from this.
+    Recomputed per compaction round (O(T)) -- the approx-MSC
+    benefit/popularity estimate reads from this.  Counted by sorting the
+    (bucket, clock) codes and searching the code boundaries: on the TPU a
+    bincount (a scatter-add) of the tracker costs more than a sort of it.
     """
     trk = state.tracker
     ok = (trk.keys >= 0) & (trk.loc == tracker.LOC_FAST)
     b = bucket_of(cfg, jnp.maximum(trk.keys, 0))
-    idx = jnp.where(ok, b * 4 + trk.clock.astype(jnp.int32), cfg.n_buckets * 4)
-    flat = jnp.bincount(idx, length=cfg.n_buckets * 4 + 1)[:-1]
-    return flat.reshape(cfg.n_buckets, 4).astype(jnp.int32)
+    nc = cfg.n_buckets * 4
+    code = jnp.where(ok, b * 4 + trk.clock.astype(jnp.int32), nc)
+    edges = jnp.searchsorted(jnp.sort(code), jnp.arange(nc + 1))
+    return (edges[1:] - edges[:-1]).reshape(cfg.n_buckets, 4) \
+        .astype(jnp.int32)
 
 
 # -------------------------------------------------------------- candidates
@@ -165,14 +169,18 @@ def approx_score(state: TierState, cfg: TierConfig, lo: jax.Array,
     nf = state.bucket_fast.astype(jnp.float32)
     ns = state.bucket_slow.astype(jnp.float32)
     ov = state.bucket_overlap.astype(jnp.float32)
-    h = bhist.astype(jnp.float32)                          # [B, 4]
-    tracked_fast = jnp.sum(h, axis=1)
-    untracked = jnp.maximum(nf - tracked_fast, 0.0)
-
-    inv = 1.0 / (jnp.arange(4, dtype=jnp.float32) + 1.0)
-    benefit = jnp.sum(w * (h @ inv + untracked))
+    h = [bhist[:, c].astype(jnp.float32) for c in range(4)]   # [B] each
+    untracked = jnp.maximum(nf - (((h[0] + h[1]) + h[2]) + h[3]), 0.0)
+    # the clock-class sums are spelled out in a fixed order (no matvec):
+    # a TPU matmul would round them through bf16, and the msc_score
+    # kernel evaluates exactly these products and sums
+    coldness = (((h[0] * 1.0 + h[1] * 0.5) + h[2] * (1.0 / 3.0))
+                + h[3] * 0.25)
+    pin = (((h[0] * probs[0] + h[1] * probs[1]) + h[2] * probs[2])
+           + h[3] * probs[3])
+    benefit = jnp.sum(w * (coldness + untracked))
     t_n = jnp.sum(w * nf)
-    pinned = jnp.sum(w * (h @ probs))
+    pinned = jnp.sum(w * pin)
     p = pinned / jnp.maximum(t_n, 1.0)
     tf_est = jnp.maximum(jnp.sum(w * ns), t_f.astype(jnp.float32))
     o = jnp.sum(w * ov) / jnp.maximum(tf_est, 1.0)

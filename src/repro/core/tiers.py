@@ -35,7 +35,7 @@ from repro.core import bloom, tracker
 from repro.core.tracker import TrackerState
 from repro.core.utils import (PADKEY, alloc_slots, build_sorted_index,
                               dedupe_keep_last, merge_index_update,
-                              sorted_lookup)
+                              sorted_lookup, sorted_position)
 
 
 class TierConfig(NamedTuple):
@@ -355,8 +355,8 @@ def apply_point_ops(state: TierState, cfg: TierConfig, keys: jax.Array,
     regression the HLO copy-budget test guards).  All three lanes share the
     index lookups and the bloom probes; pool writes are scatters whose
     targets are masked out-of-bounds (``mode="drop"``) on inactive lanes,
-    and the sorted tier-0 index is maintained with a single incremental
-    ``merge_index_update`` -- never a full-pool re-sort.
+    and the sorted tier-0 index is maintained with a single
+    ``merge_index_update``.
 
     Returns ``(state', vals, found, source)``; the get-lane outputs are
     garbage unless ``is_get``.  ``source`` is the tier index that served
@@ -387,8 +387,8 @@ def apply_point_ops(state: TierState, cfg: TierConfig, keys: jax.Array,
     keep = dedupe_keep_last(keys, valid)
 
     # ---- shared lookups -------------------------------------------------
-    fslot, flook = sorted_lookup(state.idx_keys[0], state.idx_slots[0],
-                                 keys)
+    fpos, flook = sorted_position(state.idx_keys[0], keys)
+    fslot = state.idx_slots[0][fpos]
     tomb = state.fast_ver[jnp.clip(fslot, 0)] < 0
     # raw per-lower-tier bloom answers ("key may live in tier t"); the
     # delete lane needs the OR across every lower tier
@@ -438,7 +438,7 @@ def apply_point_ops(state: TierState, cfg: TierConfig, keys: jax.Array,
 
     # ---- ONE incremental index update for both mutating lanes -----------
     dropm = jnp.zeros((nf,), bool).at[
-        jnp.where(free_d, fslot, nf)].set(True, mode="drop")
+        jnp.where(free_d, fpos, nf)].set(True, mode="drop")
     fidx_keys, fidx_slots = merge_index_update(
         state.idx_keys[0], state.idx_slots[0], dropm, keys, new_slots,
         ins_ok)

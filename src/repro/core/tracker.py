@@ -186,7 +186,10 @@ def clock_histogram(state: TrackerState) -> jax.Array:
     """
     resident = state.keys >= 0
     vals = jnp.where(resident, state.clock.astype(jnp.int32), 4)
-    return jnp.bincount(vals, length=5)[:4]
+    # four counting reductions, not a bincount (a scatter-add, far slower
+    # on the TPU)
+    return jnp.sum(vals[:, None] == jnp.arange(4, dtype=jnp.int32),
+                   axis=0, dtype=jnp.int32)
 
 
 def fast_fraction_of_tracked(state: TrackerState) -> jax.Array:

@@ -95,10 +95,17 @@ def sorted_lookup(index_keys: jax.Array, index_vals: jax.Array,
 
     Returns ``(vals, found)``; ``vals`` is garbage where ``found`` is False.
     """
+    pos, found = sorted_position(index_keys, query)
+    return index_vals[pos], found
+
+
+def sorted_position(index_keys: jax.Array,
+                    query: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """``(pos, found)``: each query's index position (clamped into the
+    index) and whether the key sits there."""
     pos = jnp.searchsorted(index_keys, query)
     pos = jnp.clip(pos, 0, index_keys.shape[0] - 1)
-    found = index_keys[pos] == query
-    return index_vals[pos], found
+    return pos, index_keys[pos] == query
 
 
 def build_sorted_index(pool_keys: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -119,88 +126,84 @@ def merge_index_update(idx_keys: jax.Array, idx_slots: jax.Array,
                        ins_slots: jax.Array, ins_valid: jax.Array
                        ) -> tuple[jax.Array, jax.Array]:
     """Incremental sorted-index maintenance: merge a batch update into a
-    PADKEY-padded sorted index without re-sorting the pool.
+    PADKEY-padded sorted index.
 
-    ``drop`` is bool[N] over POOL SLOTS: live entries whose slot is marked
-    become pads.  ``ins_*`` is a static-width batch of (key, slot) pairs to
-    insert as live entries.  Preconditions (all op paths satisfy them):
-      * inserted keys are unique within the batch and not live in the
-        index after drops are applied;
-      * ``idx_slots`` values are in [0, N).
+    ``drop`` is bool[N] over INDEX POSITIONS: the entries it marks become
+    pads.  ``ins_*`` is a static-width batch of (key, slot) pairs to
+    insert as live entries.  Precondition (all op paths satisfy it):
+    inserted keys are unique within the batch and not live in the index
+    after drops are applied.
 
-    Cost: O(N) data movement + O(B log B) batch sort + searchsorted --
-    no O(N log N) full sort.  The result's live prefix is bit-identical
-    to ``build_sorted_index`` of the updated pool; pad-entry slot values
-    are arbitrary-but-deterministic (nothing reads them: lookups and
-    scans mask on ``key != PADKEY`` before using a slot).
+    One sort of the N + B (key, slot) pairs.  A sort is the cheap way to
+    move every index entry on the TPU: a merge that scatters each entry
+    to its new position costs more than a sort of the whole index (XLA's
+    TPU scatter sorts its indices before it writes).  The result's live
+    prefix is bit-identical to ``build_sorted_index`` of the updated
+    pool; pad-entry slot values are
+    unspecified (nothing reads them: lookups and scans mask on
+    ``key != PADKEY`` before using a slot) but are a function of the
+    updated entries only, since pads sort by slot too.
     """
     n = idx_keys.shape[0]
-    live0 = idx_keys != PADKEY
-    dead = live0 & drop[jnp.clip(idx_slots, 0, n - 1)]
-    live_b = live0 & ~dead
+    keys = jnp.concatenate([jnp.where(drop, PADKEY, idx_keys),
+                            jnp.where(ins_valid, ins_keys, PADKEY)])
+    slots = jnp.concatenate([idx_slots, ins_slots.astype(jnp.int32)])
+    keys, slots = jax.lax.sort((keys, slots), num_keys=2)
+    return keys[:n], slots[:n]
 
-    # sort the (tiny) insert batch; invalid lanes pad to its tail
-    ik = jnp.where(ins_valid, ins_keys, PADKEY)
-    order = jnp.argsort(ik)
-    ik, islot = ik[order], ins_slots[order]
-    ilive = ik != PADKEY
-    n_ins = jnp.sum(ilive.astype(jnp.int32))
 
-    # inserted entry -> rank in batch + surviving base keys below it;
-    # "surviving below" = sorted position in the ORIGINAL index minus the
-    # dropped entries before that position (prefix sum of ``dead``).
-    # searchsorted here is B queries into the pool-sized array: its
-    # binary-search while loop carries only BATCH-shaped state.
-    dead_cum0 = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                                 jnp.cumsum(dead.astype(jnp.int32))])
-    p = jnp.searchsorted(idx_keys, ik).astype(jnp.int32)
-    rank_i = jnp.cumsum(ilive.astype(jnp.int32)) - 1
-    pos_i = jnp.where(ilive, rank_i + p - dead_cum0[p], n)
+def splice_index_range(idx_keys: jax.Array, idx_slots: jax.Array,
+                       start: jax.Array, n_drop: jax.Array,
+                       ins_keys: jax.Array, ins_slots: jax.Array,
+                       n_ins: jax.Array, max_drop: int
+                       ) -> tuple[jax.Array, jax.Array]:
+    """Replace the index entries ``[start, start + n_drop)`` with the
+    first ``n_ins`` entries of the sorted batch ``ins_*``.
 
-    # surviving base entry -> rank among survivors + inserted keys below
-    # it (no ties: inserted keys are fresh).  NOT a pool-length-query
-    # searchsorted (whose lowering carries pool-shaped binary-search state
-    # through a while loop, copied every iteration on XLA CPU): since
-    # ``ik[i] < idx_keys[j]  <=>  p[i] <= j``, the count is the inclusive
-    # prefix sum of a batch-position histogram -- O(n) cumsum, zero
-    # pool-shaped loop state.
-    below_i = jnp.cumsum(jnp.zeros((n,), jnp.int32).at[
-        jnp.where(ilive & (p < n), p, n)].add(1, mode="drop"))
-    rank_b = jnp.cumsum(live_b.astype(jnp.int32)) - 1
-    pos_b = jnp.where(live_b, rank_b + below_i, n)
+    The compaction form of ``merge_index_update``: a merge window drops
+    every live key of one key range -- a contiguous stretch of the sorted
+    index -- and writes its merged keys back into the same range, so the
+    update is a splice: the batch lands at ``start`` and the tail shifts
+    by ``n_drop - n_ins``, a few streaming passes over the index, far
+    cheaper than a sort of it.  ``n_drop <= max_drop`` (static).  Same
+    live prefix as ``merge_index_update``; pad-entry slots unspecified.
+    """
+    n, m = idx_keys.shape[0], ins_keys.shape[0]
+    pad = max(m, max_drop)
+    j = jnp.arange(n + m, dtype=jnp.int32)
+    fresh = jnp.arange(m, dtype=jnp.int32) < n_ins
 
-    # pads fill the tail (dropped + original pads keep their slot value);
-    # each insert consumes one pad, so the surplus falls off the end
-    n_live = rank_b[-1] + 1 + n_ins
-    rank_p = jnp.cumsum((~live_b).astype(jnp.int32)) - 1
-    pos_p = jnp.where(~live_b, n_live + rank_p, n)
+    def splice(x, ins, fill):
+        # work on n + m entries, so the batch window never clamps
+        head = jnp.concatenate([x, jnp.full((m,), fill, x.dtype)])
+        ext = jnp.concatenate([jnp.full((pad,), fill, x.dtype), x,
+                               jnp.full((pad + m,), fill, x.dtype)])
+        tail = jax.lax.dynamic_slice(ext, (pad + n_drop - n_ins,), (n + m,))
+        out = jnp.where(j < start, head, tail)
+        win = jax.lax.dynamic_slice(out, (start,), (m,))
+        out = jax.lax.dynamic_update_slice(out, jnp.where(fresh, ins, win),
+                                           (start,))
+        return out[:n]
 
-    out_keys = jnp.full((n,), PADKEY, jnp.int32)
-    out_slots = jnp.zeros((n,), jnp.int32)
-    out_keys = out_keys.at[pos_b].set(idx_keys, mode="drop")
-    out_slots = out_slots.at[pos_b].set(idx_slots, mode="drop")
-    out_slots = out_slots.at[pos_p].set(idx_slots, mode="drop")
-    out_keys = out_keys.at[pos_i].set(ik, mode="drop")
-    out_slots = out_slots.at[pos_i].set(islot, mode="drop")
-    return out_keys, out_slots
+    return (splice(idx_keys, ins_keys, PADKEY),
+            splice(idx_slots, ins_slots, jnp.int32(0)))
 
 
 def alloc_slots(pool_keys: jax.Array, want_mask: jax.Array) -> jax.Array:
     """Allocate one free slot per True in ``want_mask`` (static size).
 
     Returns int32 slots, -1 where ``want_mask`` is False or the pool is full.
-    Deterministic: lowest-numbered free slots first.
+    Deterministic: lowest-numbered free slots first.  The r-th free slot
+    is found by binary search over the running count of free slots, not
+    by ``jnp.nonzero``, whose pool-length scatter-add is far slower on
+    the TPU.
     """
-    m = int(want_mask.shape[0])
     free = pool_keys < 0
-    # rank of each request among requests; rank of each free slot among frees
+    n_free_upto = jnp.cumsum(free.astype(jnp.int32))
     req_rank = jnp.cumsum(want_mask.astype(jnp.int32)) - 1
-    free_slots = jnp.nonzero(free, size=m, fill_value=-1)[0].astype(jnp.int32)
-    slots = jnp.where(want_mask, free_slots[jnp.clip(req_rank, 0, m - 1)], -1)
+    slots = jnp.searchsorted(n_free_upto, req_rank + 1).astype(jnp.int32)
     # not enough free slots -> -1
-    n_free = jnp.sum(free.astype(jnp.int32))
-    slots = jnp.where(want_mask & (req_rank < n_free), slots, -1)
-    return slots
+    return jnp.where(want_mask & (req_rank < n_free_upto[-1]), slots, -1)
 
 
 def dedupe_keep_last(keys: jax.Array, valid: jax.Array) -> jax.Array:

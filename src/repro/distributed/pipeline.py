@@ -23,20 +23,6 @@ from repro.models import model as M
 from repro.models.common import norm
 
 
-def _shard_map(fn, *, mesh, in_specs, out_specs):
-    """shard_map across jax versions: top-level ``jax.shard_map``
-    (check_vma) on new jax, ``jax.experimental.shard_map`` (check_rep)
-    on 0.4.x.  Both checks are disabled for the same reason: the GPipe
-    rotation is deliberately stage-varying."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm_exp
-    return sm_exp(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-
-
 def pipelined_forward(mcfg: ModelConfig, mesh, params, batch, *,
                       n_micro: int = 4, backend: str = "reference"):
     """Logits via 2+-stage GPipe over the 'pod' mesh axis.
@@ -109,10 +95,12 @@ def pipelined_forward(mcfg: ModelConfig, mesh, params, batch, *,
         params["blocks"])
     wins_split = windows.reshape(n_stages, per_stage)
 
-    pp_mapped = _shard_map(
+    # the varying-manual-axes check is off: the GPipe rotation is
+    # deliberately stage-varying
+    pp_mapped = jax.shard_map(
         pp, mesh=mesh,
         in_specs=(P("pod"), P("pod"), P(), P()),
-        out_specs=P())
+        out_specs=P(), check_vma=False)
     x = pp_mapped(blocks_split, wins_split, x, positions)
     x = norm(params["final_norm"], x, mcfg.norm_kind, mcfg.norm_eps)
     head = params["embed"].T if mcfg.tie_embeddings else params["lm_head"]

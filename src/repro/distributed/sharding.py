@@ -61,13 +61,6 @@ def axis_rules(rules: dict):
         _state.rules = prev
 
 
-def _axis_size(mesh, name) -> int:
-    try:
-        return dict(zip(mesh.axis_names, mesh.axis_sizes))[name]
-    except Exception:
-        return mesh.shape[name]
-
-
 def logical_to_spec(logical, mesh, shape=None, allowed=None) -> P:
     """Map logical axis names to a PartitionSpec for `mesh`.
 
@@ -77,6 +70,7 @@ def logical_to_spec(logical, mesh, shape=None, allowed=None) -> P:
     """
     rules = current_rules()
     have = set(mesh.axis_names) if mesh is not None else set()
+    sizes = dict(zip(mesh.axis_names, mesh.axis_sizes)) if have else {}
     if allowed is not None:
         have &= set(allowed)
     out = []
@@ -91,7 +85,7 @@ def logical_to_spec(logical, mesh, shape=None, allowed=None) -> P:
         if shape is not None:
             keep, prod = [], 1
             for c in cands:
-                sz = _axis_size(mesh, c)
+                sz = sizes[c]
                 if shape[i] % (prod * sz) == 0:
                     keep.append(c)
                     prod *= sz
@@ -111,18 +105,11 @@ def constrain(x, logical):
     """with_sharding_constraint under the ambient (abstract) mesh; no-op
     when tracing without a mesh (CPU tests).  Manual axes (inside
     shard_map) are excluded -- only Auto axes may be constrained."""
-    mesh = None
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except Exception:
-        pass
-    if mesh is None or not getattr(mesh, "axis_names", ()):
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return x
-    try:
-        allowed = {n for n, t in zip(mesh.axis_names, mesh.axis_types)
-                   if "Auto" in str(t)}
-    except Exception:
-        allowed = set(mesh.axis_names)
+    allowed = {n for n, t in zip(mesh.axis_names, mesh.axis_types)
+               if t == jax.sharding.AxisType.Auto}
     if not allowed:
         return x
     spec = logical_to_spec(logical, mesh, shape=x.shape, allowed=allowed)

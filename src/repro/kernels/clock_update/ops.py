@@ -11,6 +11,8 @@ from repro.core import tracker
 from repro.core.tracker import _occ_large
 from repro.kernels.clock_update.clock_update import clock_update
 
+TILE = 1024     # table rows per grid step (a multiple of the 128-lane tile)
+
 
 def _occurrences(keys, valid):
     """Per-access count of its key in the batch (histogram path: the sort
@@ -21,15 +23,11 @@ def _occurrences(keys, valid):
     return _occ_large(sk, valid)
 
 
-def _pick_tile(capacity: int, cap: int = 512) -> int:
-    """Largest divisor of the table size <= ``cap``, or ``cap`` itself
-    (with table padding, see ``tracker_access``) when the best divisor is
-    degenerate — a prime capacity must not collapse the grid to
-    one-slot tiles."""
-    for tile in range(min(cap, capacity), 0, -1):
-        if capacity % tile == 0:
-            break
-    return tile if tile >= min(64, capacity) else min(cap, capacity)
+def _pick_tile(capacity: int, cap: int = TILE) -> int:
+    """``cap`` rows, or the capacity rounded up to whole 128-lane tiles
+    when the table is smaller; ``tracker_access`` pads the tables up to
+    a tile multiple."""
+    return min(cap, -(-capacity // 128) * 128)
 
 
 @functools.partial(jax.jit, static_argnames=("backend", "tile", "interpret"))
@@ -44,16 +42,15 @@ def tracker_access(state: tracker.TrackerState, keys, locs, valid, *,
     if tile is None:
         tile = _pick_tile(t)
     occ = _occurrences(keys, valid).astype(jnp.int32)
-    # pad the tables up to a tile multiple when the tile doesn't divide
-    # the capacity; slot hashing stays modulo the LOGICAL capacity, so
-    # padded rows are unreachable and pass through the kernel unchanged
+    slots = jnp.where(valid, tracker._slot(state, keys), -1)
+    # pad the tables up to a tile multiple; slot hashing stays modulo the
+    # LOGICAL capacity, so padded rows are unreachable and pass through
     pad = (-t) % tile
     tk, tc, tl = state.keys, state.clock, state.loc
     if pad:
         tk = jnp.concatenate([tk, jnp.full((pad,), -1, tk.dtype)])
         tc = jnp.concatenate([tc, jnp.zeros((pad,), tc.dtype)])
         tl = jnp.concatenate([tl, jnp.zeros((pad,), tl.dtype)])
-    tk, tc, tl = clock_update(tk, tc, tl, keys, occ, locs.astype(jnp.int8),
-                              valid, tile=tile, interpret=interpret,
-                              table_size=t)
+    tk, tc, tl = clock_update(tk, tc, tl, slots, keys, occ, locs,
+                              tile=tile, interpret=interpret)
     return tracker.TrackerState(tk[:t], tc[:t], tl[:t])

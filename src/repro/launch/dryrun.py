@@ -71,21 +71,6 @@ def collective_bytes(hlo_text: str) -> dict:
     return out
 
 
-def mesh_context(mesh):
-    """Ambient-mesh context across jax versions: ``jax.set_mesh`` (new),
-    ``jax.sharding.use_mesh`` (transitional), or the Mesh's own context
-    manager (0.4.x resource env).  All three make ``mesh`` ambient for
-    lowering; version-dependent extras (abstract-mesh introspection) are
-    already guarded at their call sites."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        return use_mesh(mesh)
-    return mesh
-
-
 def _sds(tree_shapes, tree_specs, mesh):
     """ShapeDtypeStructs with NamedShardings attached."""
     specs = spec_tree(tree_specs, tree_shapes, mesh)
@@ -161,7 +146,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
                               "batch": ("pod", "data", "model"),
                               "cache_head_dim": None})
 
-    with ctx, mesh_context(mesh):
+    with ctx, jax.set_mesh(mesh):
         if shape.kind == "train":
             tcfg = T.TrainConfig(micro_batches=micro_batches,
                                  compress_grads=multi_pod)
@@ -214,8 +199,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, outdir: str,
         t2 = time.time()
         ma = compiled.memory_analysis()
         ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):   # jax 0.4.x: one dict per program
-            ca = ca[0] if ca else {}
         rec["memory_analysis"] = {
             k: getattr(ma, k) for k in
             ("argument_size_in_bytes", "output_size_in_bytes",

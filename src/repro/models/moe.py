@@ -174,12 +174,8 @@ def moe_ffn_ep_local(params, cfg: ModelConfig, x):
     Falls back to the rowwise path when no mesh with data/model axes is
     ambient (CPU tests).
     """
-    mesh = None
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except Exception:
-        pass
-    if mesh is None or "model" not in getattr(mesh, "axis_names", ()):
+    mesh = jax.sharding.get_abstract_mesh()
+    if "model" not in mesh.axis_names:
         return moe_ffn_rowwise(params, cfg, x)
 
     from jax.sharding import PartitionSpec as P

@@ -2,14 +2,14 @@
 
 ``maybe_trace(None)`` is a free no-op, so callers can thread the
 ``--profile DIR`` flag straight through.  Traces are viewable with
-TensorBoard / Perfetto (see README "Observability"); capture failures
-degrade to a warning because profiler availability varies by backend.
+TensorBoard / Perfetto (see README "Observability").  A profiler that
+cannot start or stop raises: a run asked to trace must not finish
+silently without its trace.
 """
 from __future__ import annotations
 
 import contextlib
 import os
-import sys
 
 
 @contextlib.contextmanager
@@ -20,16 +20,8 @@ def maybe_trace(trace_dir: str | None):
         return
     import jax
     os.makedirs(trace_dir, exist_ok=True)
-    try:
-        jax.profiler.start_trace(trace_dir)
-    except Exception as exc:  # pragma: no cover - backend dependent
-        print(f"[obs] profiler trace unavailable: {exc}", file=sys.stderr)
-        yield None
-        return
+    jax.profiler.start_trace(trace_dir)
     try:
         yield trace_dir
     finally:
-        try:
-            jax.profiler.stop_trace()
-        except Exception as exc:  # pragma: no cover
-            print(f"[obs] profiler stop failed: {exc}", file=sys.stderr)
+        jax.profiler.stop_trace()

@@ -119,7 +119,6 @@ def run_tenants_sharded(estates: engine.EngineState, gsts: GenState,
     shared-nothing (tenant i is pinned to partition i), so no collective
     appears in the loop and the result is bit-identical to the vmapped
     ``run_tenants`` on one device -- the mesh parity tests pin it."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     axis = cfg.mesh_axis
     fn = functools.partial(run_schedule, cfg=cfg, n_batches=n_batches,
@@ -129,10 +128,10 @@ def run_tenants_sharded(estates: engine.EngineState, gsts: GenState,
         return jax.vmap(functools.partial(fn, t0=t0))(est, g, r, sch)
 
     spec, rep = P(axis), P()
-    sm = shard_map(local, mesh=mesh,
-                   in_specs=(spec, spec, spec, spec, rep),
-                   out_specs=(spec, spec, spec, spec),
-                   check_rep=False)
+    sm = jax.shard_map(local, mesh=mesh,
+                       in_specs=(spec, spec, spec, spec, rep),
+                       out_specs=(spec, spec, spec, spec),
+                       check_vma=False)
     return sm(estates, gsts, rngs, scheds, jnp.asarray(t0, jnp.int32))
 
 

@@ -19,8 +19,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core import engine
-from repro.workloads.spec import (LATEST, SEQ, UNIFORM, ZIPF, GenState,
-                                  WorkloadSpec)
+from repro.workloads.spec import (HASHED, LATEST, SEQ, UNIFORM, ZIPF,
+                                  GenState, WorkloadSpec)
 
 SCRAMBLE_MUL = 2654435761       # Knuth multiplicative constant
 
@@ -51,7 +51,7 @@ def sample_keys(key: jax.Array, dist: jax.Array, theta: jax.Array,
     """One batch of keys under a (traced) distribution code.
 
     Returns ``(keys, ptr')``; the insert pointer advances only when the
-    SEQ distribution was selected.
+    SEQ or HASHED distribution was selected.
     """
     ku, kz = jax.random.split(key)
     uni = jax.random.randint(ku, (batch,), 0, key_space, jnp.int32)
@@ -61,9 +61,10 @@ def sample_keys(key: jax.Array, dist: jax.Array, theta: jax.Array,
     latest = jnp.mod(ptr - 1 - ranks, key_space).astype(jnp.int32)
     seq = jnp.mod(ptr + jnp.arange(batch, dtype=jnp.int32),
                   key_space).astype(jnp.int32)
-    keys = jnp.select([dist == UNIFORM, dist == ZIPF, dist == LATEST],
-                      [uni, zipf, latest], seq)
-    ptr = jnp.where(dist == SEQ, ptr + batch, ptr)
+    hashed = scramble(seq, jnp.int32(0), key_space)
+    keys = jnp.select([dist == UNIFORM, dist == ZIPF, dist == LATEST,
+                       dist == HASHED], [uni, zipf, latest, hashed], seq)
+    ptr = jnp.where((dist == SEQ) | (dist == HASHED), ptr + batch, ptr)
     return keys, ptr
 
 

@@ -17,6 +17,10 @@ Twitter-cluster style):
   LATEST    zipfian over recency behind the insert pointer (YCSB-D reads)
   SEQ       sequential inserts at the pointer (YCSB-D/E writes); the
             pointer lives in ``GenState`` and advances on use
+  HASHED    the SEQ pointer's keys, scrambled (a multiplicative
+            permutation of the key space when ``key_space`` is a power of
+            two): a load in scrambled order, as YCSB's default
+            ``insertorder=hashed`` loads, though not by YCSB's hash.
 """
 from __future__ import annotations
 
@@ -25,9 +29,10 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-UNIFORM, ZIPF, LATEST, SEQ = 0, 1, 2, 3
+UNIFORM, ZIPF, LATEST, SEQ, HASHED = 0, 1, 2, 3, 4
 
-_DIST = {"uniform": UNIFORM, "zipf": ZIPF, "latest": LATEST, "seq": SEQ}
+_DIST = {"uniform": UNIFORM, "zipf": ZIPF, "latest": LATEST, "seq": SEQ,
+         "hashed": HASHED}
 
 
 class WorkloadSpec(NamedTuple):
@@ -46,7 +51,7 @@ class WorkloadSpec(NamedTuple):
 
 class GenState(NamedTuple):
     """Mutable generator state threaded through sampling: the insert
-    pointer for LATEST reads / SEQ writes."""
+    pointer for LATEST reads / SEQ and HASHED writes."""
     ptr: jax.Array          # i32
 
 

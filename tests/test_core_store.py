@@ -123,6 +123,32 @@ def test_rate_limiting_never_drops_writes():
         assert bool(jnp.all(found))
 
 
+@pytest.mark.parametrize("n_tiers", [2, 3])
+def test_sequential_load_keeps_every_record(n_tiers):
+    """YCSB's load phase (sequential puts of every key) into a bottom
+    tier sized to the key space: every record must read back with its
+    value.  Merges, at the slab boundary and at deep boundaries alike,
+    used to write their remainder as a small run of its own; those runs
+    piled up until the run directory was full, and later merges lost
+    rows."""
+    from repro import workloads as W
+    n = 4096
+    tiers = (n // 8, n) if n_tiers == 2 else (n // 8, n // 2, n)
+    cfg = CFG._replace(key_space=n, fast_slots=n // 8, slow_slots=n,
+                       tracker_slots=n // 10, pin_threshold=0.7,
+                       tier_slots=tiers)
+    db = PrismDB(cfg, seed=0)
+    db.reset_workload(seed=0)
+    db.run_workload(W.spec(read=0.0, wdist="seq"), n // 256, 256)
+    keys = np.arange(n, dtype=np.int32)
+    vals, found, _ = db.get(keys)
+    assert db.counters["compactions"] > 0
+    assert db.counters["comp_by_boundary"][-1] > 0
+    assert int((~np.asarray(found)).sum()) == 0
+    np.testing.assert_array_equal(np.asarray(vals[:, 0]),
+                                  keys.astype(np.float32))
+
+
 def _oracle_random_ops(ops):
     """Random op sequence vs a python-dict oracle."""
     cfg = TierConfig(key_space=512, fast_slots=64, slow_slots=1024,
@@ -186,3 +212,31 @@ def test_bloom_fp_rate_reasonable():
                             jnp.ones(1000, bool))
     fp = float(jnp.mean(bloom.query(filters, jnp.asarray([0]), other)))
     assert fp < 0.05, fp
+
+
+def test_full_run_directory_keeps_slow_index_consistent():
+    """Merges that find the run directory full write rows that belong to
+    no run.  A later merge over their key range must free them with the
+    rest of its window: the slow index stays the sorted index of the
+    slow pool, and no key put leaves the pools.  (Such rows are not
+    behind any run's Bloom filter, so a get does not see them: a full
+    run directory is a capacity limit, not a state to run in.)"""
+    n = 2048
+    cfg = CFG._replace(key_space=n, fast_slots=256, slow_slots=n,
+                       max_runs=4, run_size=128, tracker_slots=n // 10,
+                       n_buckets=32)
+    db = PrismDB(cfg, seed=0)
+    rng = np.random.default_rng(0)
+    for _ in range(3):
+        keys = rng.permutation(n // 2).astype(np.int32)
+        for i in range(0, len(keys), 128):
+            db.put(keys[i:i + 128])
+    st = db.state
+    slow = np.asarray(st.slow_keys)
+    assert (np.asarray(st.slow_run)[slow >= 0] == cfg.max_runs).any()
+    idx = np.asarray(st.sidx_keys)
+    np.testing.assert_array_equal(idx[idx != np.iinfo(np.int32).max],
+                                  np.sort(slow[slow >= 0]))
+    fast = np.asarray(st.fast_keys)
+    assert set(keys.tolist()) == set(slow[slow >= 0].tolist()) | \
+        set(fast[fast >= 0].tolist())

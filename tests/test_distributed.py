@@ -130,11 +130,7 @@ def test_pipeline_forward_matches_plain_inprocess():
     mesh = jax.make_mesh((2, 2), ("pod", "data"))
     want, _ = jax.jit(lambda p: M.forward(cfg, p, {"tokens": toks},
                                           remat=False))(params)
-    # ambient-mesh compat ladder (see repro.launch.dryrun.mesh_context)
-    mesh_ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else (
-        jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh")
-        else mesh)
-    with mesh_ctx:
+    with jax.set_mesh(mesh):
         got = jax.jit(lambda p: pipelined_forward(cfg, mesh, p,
                                                   {"tokens": toks},
                                                   n_micro=2))(params)
@@ -174,12 +170,7 @@ toks = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, cfg.vocab)
 mesh = jax.make_mesh((2, 2), ("pod", "data"))
 want, _ = jax.jit(lambda p: M.forward(cfg, p, {"tokens": toks},
                                       remat=False))(params)
-# ambient-mesh compat (same ladder as repro.launch.dryrun.mesh_context;
-# inlined because importing dryrun would re-set XLA_FLAGS on import)
-mesh_ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else (
-    jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh")
-    else mesh)
-with mesh_ctx:
+with jax.set_mesh(mesh):
     got = jax.jit(lambda p: pipelined_forward(cfg, mesh, p,
                                               {"tokens": toks},
                                               n_micro=2))(params)

@@ -20,8 +20,8 @@ except ImportError:
     HAVE_HYPOTHESIS = False
 
 from repro.core import PrismDB, TierConfig, engine, tiers
-from repro.core.utils import (PADKEY, build_sorted_index,
-                              merge_index_update)
+from repro.core.utils import (PADKEY, alloc_slots, build_sorted_index,
+                              merge_index_update, splice_index_range)
 
 CFG = TierConfig(key_space=512, fast_slots=64, slow_slots=1024,
                  value_width=1, max_runs=32, run_size=32,
@@ -66,7 +66,7 @@ def test_merge_update_insert_only():
 def test_merge_update_drop_only():
     pool = jnp.asarray([4, 7, 2, 3], jnp.int32)
     ik, isl = build_sorted_index(pool)
-    drop = jnp.asarray([False, True, False, True])
+    drop = jnp.asarray([False, True, False, True])[isl]   # slots 1, 3
     out_k, out_s = merge_index_update(
         ik, isl, drop, jnp.zeros(2, jnp.int32), jnp.zeros(2, jnp.int32),
         jnp.zeros(2, bool))
@@ -80,7 +80,7 @@ def test_merge_update_slot_reuse():
     demote->promote pattern) must stay consistent."""
     pool = jnp.asarray([4, 7, 2], jnp.int32)
     ik, isl = build_sorted_index(pool)
-    drop = jnp.asarray([False, True, False])
+    drop = jnp.asarray([False, True, False])[isl]          # slot 1
     out_k, out_s = merge_index_update(
         ik, isl, drop, jnp.asarray([5], jnp.int32),
         jnp.asarray([1], jnp.int32), jnp.asarray([True]))
@@ -117,12 +117,58 @@ def test_merge_update_random_vs_oracle():
         lanes_k[:nins], lanes_s[:nins], lanes_v[:nins] = ins_k, ins_s, True
         perm = rng.permutation(b)
         out_k, out_s = merge_index_update(
-            ik, isl, jnp.asarray(drop), jnp.asarray(lanes_k[perm]),
+            ik, isl, jnp.asarray(drop)[isl], jnp.asarray(lanes_k[perm]),
             jnp.asarray(lanes_s[perm]), jnp.asarray(lanes_v[perm]))
         ek, es = build_sorted_index(jnp.asarray(new_pool))
         np.testing.assert_array_equal(np.asarray(out_k), np.asarray(ek))
         np.testing.assert_array_equal(canon(out_k, out_s)[1],
                                       canon(ek, es)[1])
+
+
+def test_splice_range_random_vs_oracle():
+    """Seeded compaction-shaped updates: every live key of a key range
+    [lo, hi) drops out and fresh keys of the same range take its place
+    (fewer, as many, or more than were dropped; up to a full pool)."""
+    rng = np.random.default_rng(11)
+    n, b, max_drop = 48, 12, 16
+    for _ in range(60):
+        nlive = int(rng.integers(0, n + 1))
+        pool = np.full(n, -1, np.int32)
+        slots = rng.choice(n, nlive, replace=False)
+        pool[slots] = rng.choice(4000, nlive, replace=False).astype(np.int32)
+        ik, isl = build_sorted_index(jnp.asarray(pool))
+        live = np.sort(pool[pool >= 0])
+        start = int(rng.integers(0, nlive + 1))
+        ndrop = int(rng.integers(0, min(max_drop, nlive - start) + 1))
+        lo = int(live[start]) if start < nlive else 4000
+        hi = int(live[start + ndrop]) if start + ndrop < nlive else 4000
+        lo = int(rng.integers(int(live[start - 1]) + 1, lo + 1)) \
+            if start > 0 else 0
+        new_pool = np.where((pool >= lo) & (pool < hi), -1, pool)
+        free = np.flatnonzero(new_pool < 0)
+        cands = np.setdiff1d(np.arange(lo, hi), live)
+        nins = int(rng.integers(0, min(b, len(free), len(cands)) + 1))
+        ins_k = np.sort(rng.choice(cands, nins, replace=False))
+        ins_s = rng.choice(free, nins, replace=False)
+        new_pool[ins_s] = ins_k
+        lanes_k = np.full(b, int(PADKEY), np.int32)
+        lanes_s = np.zeros(b, np.int32)
+        lanes_k[:nins], lanes_s[:nins] = ins_k, ins_s
+        out_k, out_s = splice_index_range(
+            ik, isl, jnp.int32(start), jnp.int32(ndrop),
+            jnp.asarray(lanes_k), jnp.asarray(lanes_s), jnp.int32(nins),
+            max_drop)
+        ek, es = build_sorted_index(jnp.asarray(new_pool))
+        np.testing.assert_array_equal(np.asarray(out_k), np.asarray(ek))
+        np.testing.assert_array_equal(canon(out_k, out_s)[1],
+                                      canon(ek, es)[1])
+
+
+def test_alloc_slots_lowest_free_first():
+    pool = jnp.asarray([3, -1, 5, -1, -1, 8, -1], jnp.int32)
+    want = jnp.asarray([True, False, True, True, True, True])
+    got = np.asarray(alloc_slots(pool, want))
+    np.testing.assert_array_equal(got, [1, -1, 3, 4, 6, -1])
 
 
 # ------------------------------------------------------------ store-level

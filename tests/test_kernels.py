@@ -287,15 +287,13 @@ def test_interpret_autoresolves_by_platform():
 
 
 def test_interpret_forced_on_accelerator_warns_once():
-    import warnings
-
+    """Forcing the interpreter on an accelerator is refused, every time
+    (it used to warn once and discard the hardware)."""
     from repro.core import backend as backend_mod
-    backend_mod._warned_forced_interpret = False
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        assert backend_mod.resolve_interpret(True, platform="tpu") is True
-        assert backend_mod.resolve_interpret(True, platform="tpu") is True
-    assert len(w) == 1 and "interpret=True" in str(w[0].message)
+    for _ in range(2):
+        with pytest.raises(ValueError, match="interpret=True"):
+            backend_mod.resolve_interpret(True, platform="tpu")
+    assert backend_mod.resolve_interpret(True, platform="cpu") is True
 
 
 def test_unknown_backend_rejected():

@@ -10,7 +10,7 @@ from repro.core import PrismDB, TierConfig, engine
 from repro.core.db import PartitionedDB
 from repro.workloads import reference as R
 from repro.workloads import sampler
-from repro.workloads.spec import LATEST, UNIFORM, ZIPF
+from repro.workloads.spec import HASHED, LATEST, UNIFORM, ZIPF
 
 CFG = TierConfig(key_space=1 << 12, fast_slots=256, slow_slots=1 << 12,
                  value_width=2, max_runs=64, run_size=128,
@@ -97,6 +97,19 @@ def test_latest_sampler_concentrates_behind_insert_pointer():
     # analytic CDF at rank 31 for theta=1.5 is ~0.85
     assert (dist < 32).mean() > 0.75
     assert dist.mean() < KS / 8
+
+
+def test_hashed_inserts_permute_the_key_space():
+    """A scrambled load order: key_space inserts from the pointer load
+    every key exactly once, out of order."""
+    ks = 1 << 12
+    keys, ptr = sampler.sample_keys(jax.random.PRNGKey(5), jnp.int32(HASHED),
+                                    jnp.float32(0.0), jnp.int32(0),
+                                    jnp.int32(ks // 2), ks, ks)
+    keys = np.asarray(keys)
+    assert int(ptr) == ks // 2 + ks
+    assert np.array_equal(np.sort(keys), np.arange(ks))
+    assert np.abs(np.diff(keys)).mean() > ks / 8     # not sequential
 
 
 def test_hot_offset_moves_the_hot_set():
