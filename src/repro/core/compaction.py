@@ -123,264 +123,286 @@ def compact_once(state: TierState, cfg: TierConfig, rng: jax.Array,
     cap_slow = cap_slow or 2 * cfg.run_size * max(cfg.range_fanout_i, 1)
     r_sel, r_pin, r_pro = jax.random.split(rng, 3)
 
-    cand, scores, best = msc.select_range(state, cfg, r_sel, precise=precise,
-                                          cap_fast=cap_fast,
-                                          cap_slow=cap_slow,
-                                          selection=selection,
-                                          backend=backend,
-                                          interpret=interpret)
-    lo, hi = cand.lo[best], cand.hi[best]
-    run_start, run_span = cand.run_start[best], cand.run_span[best]
-    # A window reads at most cap_slow slow rows.  Runs stay below that
-    # (``sub_runs``), but rows a merge wrote when the run directory had no
-    # free entry can push a key range past it: the window then ends at the
-    # first row it cannot read, so that the merged rows splice back into
-    # the slow index in order.
-    s_lo = jnp.searchsorted(state.sidx_keys, lo)
-    over = jnp.searchsorted(state.sidx_keys, hi) - s_lo > cap_slow
-    hi = jnp.where(over, state.sidx_keys[jnp.minimum(
-        s_lo + cap_slow, state.sidx_keys.shape[0] - 1)], hi)
+    with jax.named_scope("select"):
+        cand, scores, best = msc.select_range(
+            state, cfg, r_sel, precise=precise, cap_fast=cap_fast,
+            cap_slow=cap_slow, selection=selection, backend=backend,
+            interpret=interpret)
+        lo, hi = cand.lo[best], cand.hi[best]
+        run_start, run_span = cand.run_start[best], cand.run_span[best]
+        # A window reads at most cap_slow slow rows.  Runs stay below that
+        # (``sub_runs``), but rows a merge wrote when the run directory had
+        # no free entry can push a key range past it: the window then ends
+        # at the first row it cannot read, so that the merged rows splice
+        # back into the slow index in order.
+        s_lo = jnp.searchsorted(state.sidx_keys, lo)
+        over = jnp.searchsorted(state.sidx_keys, hi) - s_lo > cap_slow
+        hi = jnp.where(over, state.sidx_keys[jnp.minimum(
+            s_lo + cap_slow, state.sidx_keys.shape[0] - 1)], hi)
 
-    hist = tracker.clock_histogram(state.tracker)
-    # capacity guard (beyond-paper; the paper defers threshold tuning to
-    # future work): the pin budget must leave headroom below fast capacity,
-    # else compactions cannot free space and the system death-spirals when
-    # tracked_keys * threshold > fast_slots (e.g. a 5% fast tier).
-    tracked_total = jnp.maximum(jnp.sum(hist).astype(jnp.float32), 1.0)
-    cap_frac = 0.6 * cfg.fast_slots / tracked_total
-    threshold = jnp.minimum(jnp.float32(cfg.pin_threshold), cap_frac)
-    probs = mapper.pin_probabilities(hist, threshold)
+    with jax.named_scope("demote"):
+        hist = tracker.clock_histogram(state.tracker)
+        # capacity guard (beyond-paper; the paper defers threshold tuning
+        # to future work): the pin budget must leave headroom below fast
+        # capacity, else compactions cannot free space and the system
+        # death-spirals when tracked_keys * threshold > fast_slots (e.g. a
+        # 5% fast tier).
+        tracked_total = jnp.maximum(jnp.sum(hist).astype(jnp.float32), 1.0)
+        cap_frac = 0.6 * cfg.fast_slots / tracked_total
+        threshold = jnp.minimum(jnp.float32(cfg.pin_threshold), cap_frac)
+        probs = mapper.pin_probabilities(hist, threshold)
 
-    # ---- fast-tier range: pin or demote --------------------------------
-    fpos, fm = segment_in_range(state.fidx_keys, lo, hi, cap_fast)
-    fkeys = jnp.where(fm, state.fidx_keys[fpos], PADKEY)
-    fslots = jnp.where(fm, state.fidx_slots[fpos], 0)
-    tomb = state.fast_ver[fslots] < 0
-    clock, tracked = tracker.lookup_clock(state.tracker, fkeys)
-    if pin_mode == "none":
-        pinned = jnp.zeros_like(fm)
-    elif pin_mode == "file":
-        # Mutant-style file granularity: the whole range stays hot iff its
-        # average pin probability crosses 1/2 (single placement decision
-        # per file -- the coarseness the paper criticizes in §7.1).
-        per_obj = probs[jnp.clip(clock.astype(jnp.int32), 0, 3)] \
-            * tracked.astype(jnp.float32)
-        avg = jnp.sum(jnp.where(fm, per_obj, 0.0)) \
-            / jnp.maximum(jnp.sum(fm.astype(jnp.float32)), 1.0)
-        pinned = fm & ~tomb & (avg >= 0.5)
-    else:
-        pinned = mapper.pin_decisions(clock, tracked, probs, r_pin) \
-            & fm & ~tomb
-    if force_pin_keys is not None:
-        pos_f = jnp.clip(jnp.searchsorted(force_pin_keys, fkeys), 0,
-                         force_pin_keys.shape[0] - 1)
-        forced = force_pin_keys[pos_f] == fkeys
-        pinned = pinned | (forced & fm & ~tomb)
-    demote = fm & ~pinned                 # tombstones always leave fast tier
-    demote_data = demote & ~tomb          # tombstones carry no payload
+        # ---- fast-tier range: pin or demote --------------------------------
+        fpos, fm = segment_in_range(state.fidx_keys, lo, hi, cap_fast)
+        fkeys = jnp.where(fm, state.fidx_keys[fpos], PADKEY)
+        fslots = jnp.where(fm, state.fidx_slots[fpos], 0)
+        tomb = state.fast_ver[fslots] < 0
+        clock, tracked = tracker.lookup_clock(state.tracker, fkeys)
+        if pin_mode == "none":
+            pinned = jnp.zeros_like(fm)
+        elif pin_mode == "file":
+            # Mutant-style file granularity: the whole range stays hot iff
+            # its average pin probability crosses 1/2 (single placement
+            # decision per file -- the coarseness the paper criticizes in
+            # §7.1).
+            per_obj = probs[jnp.clip(clock.astype(jnp.int32), 0, 3)] \
+                * tracked.astype(jnp.float32)
+            avg = jnp.sum(jnp.where(fm, per_obj, 0.0)) \
+                / jnp.maximum(jnp.sum(fm.astype(jnp.float32)), 1.0)
+            pinned = fm & ~tomb & (avg >= 0.5)
+        else:
+            pinned = mapper.pin_decisions(clock, tracked, probs, r_pin) \
+                & fm & ~tomb
+        if force_pin_keys is not None:
+            pos_f = jnp.clip(jnp.searchsorted(force_pin_keys, fkeys), 0,
+                             force_pin_keys.shape[0] - 1)
+            forced = force_pin_keys[pos_f] == fkeys
+            pinned = pinned | (forced & fm & ~tomb)
+        demote = fm & ~pinned          # tombstones always leave fast tier
+        demote_data = demote & ~tomb   # tombstones carry no payload
 
-    # ---- slow-tier window ----------------------------------------------
-    spos, sm = segment_in_range(state.sidx_keys, lo, hi, cap_slow)
-    skeys = jnp.where(sm, state.sidx_keys[spos], PADKEY)
-    sslots = jnp.where(sm, state.sidx_slots[spos], 0)
-    _, in_fast = sorted_lookup(state.fidx_keys, state.fidx_slots, skeys)
-    superseded = in_fast & sm             # any live fast copy (or tombstone)
+        # ---- slow-tier window ----------------------------------------------
+        spos, sm = segment_in_range(state.sidx_keys, lo, hi, cap_slow)
+        skeys = jnp.where(sm, state.sidx_keys[spos], PADKEY)
+        sslots = jnp.where(sm, state.sidx_slots[spos], 0)
+        _, in_fast = sorted_lookup(state.fidx_keys, state.fidx_slots, skeys)
+        superseded = in_fast & sm      # any live fast copy (or tombstone)
 
-    # ---- free demoted fast slots, then install promotions ----------------
-    # Promotions (paper §4.2): the compaction already paid the run read, so
-    # hot slow-tier objects may ride back to the fast tier.  Two guards keep
-    # promotion from fighting demotion: (a) only objects whose whole clock
-    # class fits in the pin budget (the hottest class, typically clock=3);
-    # (b) never promote more than this compaction demoted, so compactions
-    # monotonically free space.  Allocation happens BEFORE the merge set is
-    # fixed: a failed allocation keeps the object in the new run (no loss).
-    nf = state.fast_keys.shape[0]
-    ftgt = jnp.where(demote, fslots, nf)
-    fast_keys = state.fast_keys.at[ftgt].set(-1, mode="drop")
-    fast_ver = state.fast_ver.at[ftgt].set(0, mode="drop")
+        # ---- free demoted fast slots, then install promotions ---------------
+        # Promotions (paper §4.2): the compaction already paid the run
+        # read, so hot slow-tier objects may ride back to the fast tier.
+        # Two guards keep promotion from fighting demotion: (a) only objects
+        # whose whole clock class fits in the pin budget (the hottest class,
+        # typically clock=3); (b) never promote more than this compaction
+        # demoted, so compactions monotonically free space.  Allocation
+        # happens BEFORE the merge set is fixed: a failed allocation keeps
+        # the object in the new run (no loss).
+        nf = state.fast_keys.shape[0]
+        ftgt = jnp.where(demote, fslots, nf)
+        fast_keys = state.fast_keys.at[ftgt].set(-1, mode="drop")
+        fast_ver = state.fast_ver.at[ftgt].set(0, mode="drop")
 
-    n_dem_total = jnp.sum(demote.astype(jnp.int32))
-    sclock, stracked = tracker.lookup_clock(state.tracker, skeys)
-    fully_pinned = probs[jnp.clip(sclock.astype(jnp.int32), 0, 3)] >= 0.999
-    promote_want = (sm & ~superseded & stracked & fully_pinned
-                    & (sclock >= cfg.promote_min_clock)) if promote \
-        else jnp.zeros_like(sm)
-    if cfg.n_tiers > 2:
-        # tier-1 tombstone ROWS (deep-boundary delete carriers) are not
-        # data: never promote them back to the slab tier
-        stomb = state.tombs[0][sslots]
-        promote_want = promote_want & ~stomb
-    rank = jnp.cumsum(promote_want.astype(jnp.int32)) - 1
-    promote_want = promote_want & (rank < n_dem_total)
-    pro_slots = alloc_slots(fast_keys, promote_want)
-    pro_ok = promote_want & (pro_slots >= 0)
-    ptgt = jnp.where(pro_ok, pro_slots, nf)
-    fast_keys = fast_keys.at[ptgt].set(skeys, mode="drop")
-    fast_vals = state.fast_vals.at[ptgt].set(state.slow_vals[sslots],
-                                             mode="drop")
-    fast_ver = fast_ver.at[ptgt].set(1, mode="drop")
-    # incremental index maintenance: drop the demoted slots, merge in the
-    # promotions
-    dropf = jnp.zeros((nf,), bool).at[
-        jnp.where(demote, fpos, nf)].set(True, mode="drop")
-    fidx_keys, fidx_slots = merge_index_update(
-        state.fidx_keys, state.fidx_slots, dropf, skeys, pro_slots, pro_ok)
+        n_dem_total = jnp.sum(demote.astype(jnp.int32))
+        sclock, stracked = tracker.lookup_clock(state.tracker, skeys)
+        fully_pinned = (probs[jnp.clip(sclock.astype(jnp.int32), 0, 3)]
+                        >= 0.999)
+        promote_want = (sm & ~superseded & stracked & fully_pinned
+                        & (sclock >= cfg.promote_min_clock)) if promote \
+            else jnp.zeros_like(sm)
+        if cfg.n_tiers > 2:
+            # tier-1 tombstone ROWS (deep-boundary delete carriers) are not
+            # data: never promote them back to the slab tier
+            stomb = state.tombs[0][sslots]
+            promote_want = promote_want & ~stomb
+        rank = jnp.cumsum(promote_want.astype(jnp.int32)) - 1
+        promote_want = promote_want & (rank < n_dem_total)
+        pro_slots = alloc_slots(fast_keys, promote_want)
+        pro_ok = promote_want & (pro_slots >= 0)
+        ptgt = jnp.where(pro_ok, pro_slots, nf)
+        fast_keys = fast_keys.at[ptgt].set(skeys, mode="drop")
+        fast_vals = state.fast_vals.at[ptgt].set(state.slow_vals[sslots],
+                                                 mode="drop")
+        fast_ver = fast_ver.at[ptgt].set(1, mode="drop")
+        # incremental index maintenance: drop the demoted slots, merge in
+        # the promotions
+        dropf = jnp.zeros((nf,), bool).at[
+            jnp.where(demote, fpos, nf)].set(True, mode="drop")
+        fidx_keys, fidx_slots = merge_index_update(
+            state.fidx_keys, state.fidx_slots, dropf, skeys, pro_slots,
+            pro_ok)
 
-    survive = sm & ~superseded & ~pro_ok
+        survive = sm & ~superseded & ~pro_ok
 
-    # ---- merge (sorted; PADKEY sorts to the tail) ------------------------
-    if cfg.n_tiers > 2:
-        # A tier-0 tombstone cannot simply vanish at boundary 0 when a
-        # copy may survive in tiers >= 2: bloom-positive-anywhere-deeper
-        # tombstones ride the merge into tier 1 as tombstone ROWS
-        # (paper §6 generalized; dropped once no deeper tier remains).
-        # Surviving tier-1 tombstone rows are likewise dropped as soon
-        # as every deeper bloom goes negative.
-        deeper_f = _maybe_deeper(state, cfg, fkeys, below=1)
-        deeper_s = _maybe_deeper(state, cfg, skeys, below=1)
-        tomb_keep = demote & tomb & deeper_f
-        survive = survive & (~stomb | deeper_s)
-        f_half = demote_data | tomb_keep
-        mtomb_half = jnp.concatenate([tomb_keep, stomb & survive])
-    else:
-        f_half = demote_data
-    mkeys = jnp.concatenate([jnp.where(f_half, fkeys, PADKEY),
-                             jnp.where(survive, skeys, PADKEY)])
-    mvals = jnp.concatenate([state.fast_vals[fslots], state.slow_vals[sslots]])
-    order = jnp.argsort(mkeys)
-    mkeys, mvals = mkeys[order], mvals[order]
-    mvalid = mkeys != PADKEY
-    n_merged = jnp.sum(mvalid.astype(jnp.int32))
+    with jax.named_scope("merge"):
+        # ---- merge (sorted; PADKEY sorts to the tail) -----------------------
+        if cfg.n_tiers > 2:
+            # A tier-0 tombstone cannot simply vanish at boundary 0 when a
+            # copy may survive in tiers >= 2: bloom-positive-anywhere-deeper
+            # tombstones ride the merge into tier 1 as tombstone ROWS
+            # (paper §6 generalized; dropped once no deeper tier remains).
+            # Surviving tier-1 tombstone rows are likewise dropped as soon
+            # as every deeper bloom goes negative.
+            deeper_f = _maybe_deeper(state, cfg, fkeys, below=1)
+            deeper_s = _maybe_deeper(state, cfg, skeys, below=1)
+            tomb_keep = demote & tomb & deeper_f
+            survive = survive & (~stomb | deeper_s)
+            f_half = demote_data | tomb_keep
+            mtomb_half = jnp.concatenate([tomb_keep, stomb & survive])
+        else:
+            f_half = demote_data
+        mkeys = jnp.concatenate([jnp.where(f_half, fkeys, PADKEY),
+                                 jnp.where(survive, skeys, PADKEY)])
+        mvals = jnp.concatenate([state.fast_vals[fslots],
+                                 state.slow_vals[sslots]])
+        order = jnp.argsort(mkeys)
+        mkeys, mvals = mkeys[order], mvals[order]
+        mvalid = mkeys != PADKEY
+        n_merged = jnp.sum(mvalid.astype(jnp.int32))
 
-    # ---- free the window runs' slots -------------------------------------
-    r = cfg.max_runs
-    # map window positions in lo-order back to run ids
-    lo_key = jnp.where(state.run_active, state.run_lo, PADKEY)
-    order_runs = jnp.argsort(lo_key)
-    pos_in_order = jnp.searchsorted(lo_key[order_runs], state.run_lo[
-        jnp.clip(run_start, 0, r - 1)])
-    win_pos = pos_in_order + jnp.arange(cfg.range_fanout_i, dtype=jnp.int32)
-    win_rids = jnp.where(
-        (run_start >= 0) & (jnp.arange(cfg.range_fanout_i) < run_span),
-        order_runs[jnp.clip(win_pos, 0, r - 1)], r).astype(jnp.int32)
+    with jax.named_scope("write_runs"):
+        # ---- free the window runs' slots ------------------------------------
+        r = cfg.max_runs
+        # map window positions in lo-order back to run ids
+        lo_key = jnp.where(state.run_active, state.run_lo, PADKEY)
+        order_runs = jnp.argsort(lo_key)
+        pos_in_order = jnp.searchsorted(lo_key[order_runs], state.run_lo[
+            jnp.clip(run_start, 0, r - 1)])
+        win_pos = pos_in_order + jnp.arange(cfg.range_fanout_i,
+                                            dtype=jnp.int32)
+        win_rids = jnp.where(
+            (run_start >= 0) & (jnp.arange(cfg.range_fanout_i) < run_span),
+            order_runs[jnp.clip(win_pos, 0, r - 1)], r).astype(jnp.int32)
 
-    # the rows the window read leave the pool: the window runs' rows, and
-    # any row of [lo, hi) that a merge wrote when the run directory had no
-    # free entry (its run id is max_runs).  Freeing by run id alone would
-    # leave such rows behind in the middle of the index range the splice
-    # below replaces.
-    ns = state.slow_keys.shape[0]
-    in_window = jnp.zeros((ns,), bool).at[
-        jnp.where(sm, sslots, ns)].set(True, mode="drop")
-    slow_keys = jnp.where(in_window, -1, state.slow_keys)
-    slow_run = jnp.where(in_window, -1, state.slow_run)
+        # the rows the window read leave the pool: the window runs' rows,
+        # and any row of [lo, hi) that a merge wrote when the run directory
+        # had no free entry (its run id is max_runs).  Freeing by run id
+        # alone would leave such rows behind in the middle of the index
+        # range the splice below replaces.
+        ns = state.slow_keys.shape[0]
+        in_window = jnp.zeros((ns,), bool).at[
+            jnp.where(sm, sslots, ns)].set(True, mode="drop")
+        slow_keys = jnp.where(in_window, -1, state.slow_keys)
+        slow_run = jnp.where(in_window, -1, state.slow_run)
 
-    # ---- write the merged output as sub-runs ----------------------------
-    # (the paper writes "new SST file(s)": splitting keeps run sizes
-    # bounded)
-    sub_of, n_sub = sub_runs(mvalid, n_merged, cfg.run_size)
+        # ---- write the merged output as sub-runs ----------------------------
+        # (the paper writes "new SST file(s)": splitting keeps run sizes
+        # bounded)
+        sub_of, n_sub = sub_runs(mvalid, n_merged, cfg.run_size)
 
-    new_slots = alloc_slots(slow_keys, mvalid)
-    wrote = mvalid & (new_slots >= 0)
-    stgt = jnp.where(wrote, new_slots, slow_keys.shape[0])
-    slow_keys = slow_keys.at[stgt].set(mkeys, mode="drop")
-    slow_vals = state.slow_vals.at[stgt].set(mvals, mode="drop")
-    if cfg.n_tiers > 2:
-        mtomb = mtomb_half[order]
-        tombs0 = jnp.where(in_window, False, state.tombs[0])
-        tombs0 = tombs0.at[stgt].set(mtomb, mode="drop")
+        new_slots = alloc_slots(slow_keys, mvalid)
+        wrote = mvalid & (new_slots >= 0)
+        stgt = jnp.where(wrote, new_slots, slow_keys.shape[0])
+        slow_keys = slow_keys.at[stgt].set(mkeys, mode="drop")
+        slow_vals = state.slow_vals.at[stgt].set(mvals, mode="drop")
+        if cfg.n_tiers > 2:
+            mtomb = mtomb_half[order]
+            tombs0 = jnp.where(in_window, False, state.tombs[0])
+            tombs0 = tombs0.at[stgt].set(mtomb, mode="drop")
 
-    run_active = state.run_active.at[win_rids].set(False, mode="drop")
-    run_count = state.run_count.at[win_rids].set(0, mode="drop")
-    run_lo = state.run_lo
-    run_hi = state.run_hi
-    free_rids = jnp.nonzero(~run_active, size=n_sub, fill_value=r)[0] \
-        .astype(jnp.int32)
-    slow_run = slow_run.at[stgt].set(free_rids[jnp.clip(sub_of, 0, n_sub - 1)],
-                                     mode="drop")
-    # slow index: the window read one contiguous stretch of the index
-    # (every slow key of [lo, hi)); the merged writes (a sorted prefix of
-    # mkeys, all in [lo, hi)) take its place
-    sidx_keys, sidx_slots = splice_index_range(
-        state.sidx_keys, state.sidx_slots,
-        jnp.searchsorted(state.sidx_keys, lo).astype(jnp.int32),
-        jnp.sum(sm.astype(jnp.int32)), mkeys, new_slots,
-        jnp.sum(wrote.astype(jnp.int32)), cap_slow)
+        run_active = state.run_active.at[win_rids].set(False, mode="drop")
+        run_count = state.run_count.at[win_rids].set(0, mode="drop")
+        run_lo = state.run_lo
+        run_hi = state.run_hi
+        free_rids = jnp.nonzero(~run_active, size=n_sub, fill_value=r)[0] \
+            .astype(jnp.int32)
+        slow_run = slow_run.at[stgt].set(
+            free_rids[jnp.clip(sub_of, 0, n_sub - 1)], mode="drop")
 
-    # per-sub-run counts and key bounds
-    sub_counts = jnp.zeros((n_sub,), jnp.int32).at[sub_of].add(
-        wrote.astype(jnp.int32))
-    sub_first = jnp.full((n_sub,), PADKEY, jnp.int32).at[sub_of].min(
-        jnp.where(wrote, mkeys, PADKEY))
-    # sub-run j owns [first_j (or lo for j=0), first_{j+1}) ; last owns to hi
-    sub_lo = jnp.where(jnp.arange(n_sub) == 0, lo, sub_first)
-    nxt_first = jnp.concatenate([sub_first[1:], jnp.array([PADKEY], jnp.int32)])
-    sub_hi = jnp.minimum(nxt_first, hi)
-    sub_ok = sub_counts > 0
-    dir_tgt = jnp.where(sub_ok, free_rids, r)
-    run_active = run_active.at[dir_tgt].set(True, mode="drop")
-    run_lo = run_lo.at[dir_tgt].set(sub_lo, mode="drop")
-    run_hi = run_hi.at[dir_tgt].set(sub_hi, mode="drop")
-    run_count = run_count.at[dir_tgt].set(sub_counts, mode="drop")
-    blooms = state.blooms
-    for j in range(n_sub):                 # static unroll: n_sub is small
-        blooms = jax.lax.cond(
-            sub_ok[j],
-            lambda bl: bloom.set_run(bl, free_rids[j], mkeys,
-                                     wrote & (sub_of == j)),
-            lambda bl: bl, blooms)
+    with jax.named_scope("slow_index"):
+        # the window read one contiguous stretch of the index (every slow
+        # key of [lo, hi)); the merged writes (a sorted prefix of mkeys,
+        # all in [lo, hi)) take its place
+        sidx_keys, sidx_slots = splice_index_range(
+            state.sidx_keys, state.sidx_slots,
+            jnp.searchsorted(state.sidx_keys, lo).astype(jnp.int32),
+            jnp.sum(sm.astype(jnp.int32)), mkeys, new_slots,
+            jnp.sum(wrote.astype(jnp.int32)), cap_slow)
 
-    # ---- tracker location bits ------------------------------------------
-    trk = tracker.set_location(state.tracker, fkeys,
-                               jnp.full(fkeys.shape, 1, jnp.int8), demote)
-    trk = tracker.set_location(trk, skeys, jnp.full(skeys.shape, 0, jnp.int8),
-                               pro_ok)
+    with jax.named_scope("write_runs"):
+        # per-sub-run counts and key bounds
+        sub_counts = jnp.zeros((n_sub,), jnp.int32).at[sub_of].add(
+            wrote.astype(jnp.int32))
+        sub_first = jnp.full((n_sub,), PADKEY, jnp.int32).at[sub_of].min(
+            jnp.where(wrote, mkeys, PADKEY))
+        # sub-run j owns [first_j (or lo for j=0), first_{j+1}) ; last owns
+        # to hi
+        sub_lo = jnp.where(jnp.arange(n_sub) == 0, lo, sub_first)
+        nxt_first = jnp.concatenate([sub_first[1:],
+                                     jnp.array([PADKEY], jnp.int32)])
+        sub_hi = jnp.minimum(nxt_first, hi)
+        sub_ok = sub_counts > 0
+        dir_tgt = jnp.where(sub_ok, free_rids, r)
+        run_active = run_active.at[dir_tgt].set(True, mode="drop")
+        run_lo = run_lo.at[dir_tgt].set(sub_lo, mode="drop")
+        run_hi = run_hi.at[dir_tgt].set(sub_hi, mode="drop")
+        run_count = run_count.at[dir_tgt].set(sub_counts, mode="drop")
 
-    # ---- bucket statistics ----------------------------------------------
-    nb = cfg.n_buckets
-    fb = bucket_of(cfg, fkeys)
-    sb = bucket_of(cfg, skeys)
-    mb = bucket_of(cfg, mkeys)
-    bucket_fast = state.bucket_fast
-    bucket_fast = bucket_fast.at[jnp.where(demote, fb, nb)].add(-1, mode="drop")
-    bucket_fast = bucket_fast.at[jnp.where(pro_ok, sb, nb)].add(1, mode="drop")
-    bucket_slow = state.bucket_slow
-    bucket_slow = bucket_slow.at[jnp.where(sm, sb, nb)].add(-1, mode="drop")
-    bucket_slow = bucket_slow.at[jnp.where(wrote, mb, nb)].add(1, mode="drop")
-    # overlaps within [lo, hi) are fully resolved by the merge
-    b_width = max(cfg.key_space // nb, 1)
-    edges_lo = jnp.arange(nb, dtype=jnp.int32) * b_width
-    cover = jnp.clip((jnp.minimum(edges_lo + b_width, hi)
-                      - jnp.maximum(edges_lo, lo)).astype(jnp.float32)
-                     / float(b_width), 0.0, 1.0)
-    bucket_overlap = (state.bucket_overlap.astype(jnp.float32)
-                      * (1.0 - cover)).astype(jnp.int32)
+    with jax.named_scope("blooms"):
+        blooms = state.blooms
+        for j in range(n_sub):         # static unroll: n_sub is small
+            blooms = jax.lax.cond(
+                sub_ok[j],
+                lambda bl: bloom.set_run(bl, free_rids[j], mkeys,
+                                         wrote & (sub_of == j)),
+                lambda bl: bl, blooms)
 
-    # ---- counters (object units; bytes derived at report time) -----------
-    t_f = jnp.sum(sm.astype(jnp.int32))
-    n_dem = jnp.sum(demote_data.astype(jnp.int32))
-    n_pro = jnp.sum(pro_ok.astype(jnp.int32))
-    n_sup = jnp.sum(superseded.astype(jnp.int32))
-    nt = cfg.n_tiers
-    rinc = jnp.zeros((nt,), jnp.int32).at[0].set(n_dem).at[1].set(t_f)
-    winc = jnp.zeros((nt,), jnp.int32).at[0].set(n_pro).at[1].set(n_merged)
-    crinc = jnp.zeros((nt,), jnp.int32).at[1].set(t_f)
-    ctr = state.ctr._replace(
-        compactions=state.ctr.compactions + 1,
-        demoted=state.ctr.demoted + n_dem,
-        promoted=state.ctr.promoted + n_pro,
-        reads=state.ctr.reads + rinc,
-        comp_reads=state.ctr.comp_reads + crinc,
-        writes=state.ctr.writes + winc,
-        comp_by_boundary=state.ctr.comp_by_boundary.at[0].add(1),
-        rate_limited=state.ctr.rate_limited
-        + jnp.sum((mvalid & ~wrote).astype(jnp.int32)),
-    )
+    with jax.named_scope("stats"):
+        # ---- tracker location bits ------------------------------------------
+        trk = tracker.set_location(state.tracker, fkeys,
+                                   jnp.full(fkeys.shape, 1, jnp.int8), demote)
+        trk = tracker.set_location(trk, skeys,
+                                   jnp.full(skeys.shape, 0, jnp.int8), pro_ok)
 
-    stats = CompactionStats(
-        selected_lo=lo, selected_hi=hi, score=scores[best],
-        n_demoted=n_dem, n_promoted=n_pro, n_merged=n_merged,
-        n_superseded=n_sup, n_run_read=t_f, n_run_written=n_merged)
+        # ---- bucket statistics ----------------------------------------------
+        nb = cfg.n_buckets
+        fb = bucket_of(cfg, fkeys)
+        sb = bucket_of(cfg, skeys)
+        mb = bucket_of(cfg, mkeys)
+        bucket_fast = state.bucket_fast
+        bucket_fast = bucket_fast.at[jnp.where(demote, fb, nb)].add(
+            -1, mode="drop")
+        bucket_fast = bucket_fast.at[jnp.where(pro_ok, sb, nb)].add(
+            1, mode="drop")
+        bucket_slow = state.bucket_slow
+        bucket_slow = bucket_slow.at[jnp.where(sm, sb, nb)].add(
+            -1, mode="drop")
+        bucket_slow = bucket_slow.at[jnp.where(wrote, mb, nb)].add(
+            1, mode="drop")
+        # overlaps within [lo, hi) are fully resolved by the merge
+        b_width = max(cfg.key_space // nb, 1)
+        edges_lo = jnp.arange(nb, dtype=jnp.int32) * b_width
+        cover = jnp.clip((jnp.minimum(edges_lo + b_width, hi)
+                          - jnp.maximum(edges_lo, lo)).astype(jnp.float32)
+                         / float(b_width), 0.0, 1.0)
+        bucket_overlap = (state.bucket_overlap.astype(jnp.float32)
+                          * (1.0 - cover)).astype(jnp.int32)
+
+        # ---- counters (object units; bytes derived at report time) ----------
+        t_f = jnp.sum(sm.astype(jnp.int32))
+        n_dem = jnp.sum(demote_data.astype(jnp.int32))
+        n_pro = jnp.sum(pro_ok.astype(jnp.int32))
+        n_sup = jnp.sum(superseded.astype(jnp.int32))
+        nt = cfg.n_tiers
+        rinc = jnp.zeros((nt,), jnp.int32).at[0].set(n_dem).at[1].set(t_f)
+        winc = jnp.zeros((nt,), jnp.int32).at[0].set(n_pro).at[1].set(
+            n_merged)
+        crinc = jnp.zeros((nt,), jnp.int32).at[1].set(t_f)
+        ctr = state.ctr._replace(
+            compactions=state.ctr.compactions + 1,
+            demoted=state.ctr.demoted + n_dem,
+            promoted=state.ctr.promoted + n_pro,
+            reads=state.ctr.reads + rinc,
+            comp_reads=state.ctr.comp_reads + crinc,
+            writes=state.ctr.writes + winc,
+            comp_by_boundary=state.ctr.comp_by_boundary.at[0].add(1),
+            rate_limited=state.ctr.rate_limited
+            + jnp.sum((mvalid & ~wrote).astype(jnp.int32)),
+        )
+
+        stats = CompactionStats(
+            selected_lo=lo, selected_hi=hi, score=scores[best],
+            n_demoted=n_dem, n_promoted=n_pro, n_merged=n_merged,
+            n_superseded=n_sup, n_run_read=t_f, n_run_written=n_merged)
 
     new_state = state.update(
         fast_keys=fast_keys, fast_vals=fast_vals, fast_ver=fast_ver,

@@ -27,6 +27,13 @@ from repro.obs.state import ObsConfig
 PART_AXIS = "part"          # mesh axis name for the partition dimension
 
 
+def _span(name: str, seq: int):
+    """A host span in a profiler trace, on the same clock as the device
+    ops; a native no-op while no profiler runs.  ``seq`` is the batch's
+    sequence number (``dispatches``), shared by every span of one batch."""
+    return jax.profiler.TraceAnnotation(name, seq=seq)
+
+
 def _sync_obs(obs: ObsConfig | None, cfg: TierConfig) -> ObsConfig:
     """Keep the obs plane's tier count in lockstep with the tier config
     (it sizes the timeline rows and the per-boundary job counters)."""
@@ -42,6 +49,11 @@ class PrismDB:
     ``dispatches`` counts jitted engine calls issued by this facade: in the
     steady state it is exactly one per client batch (the harness reports
     dispatches per 1k ops from it).
+
+    Each client call is a profiler span (``prism.put`` / ``get`` /
+    ``delete`` / ``scan``) holding ``prism.make_op`` (building the
+    OpBatch, host-to-device copies included) and ``prism.dispatch`` (the
+    jitted step call), all tagged with the batch's ``dispatches`` number.
 
     A single batch can never exceed ``fast_slots`` live keys: the rate
     limiter frees space *before* the insert, but no amount of compaction
@@ -108,24 +120,29 @@ class PrismDB:
         return self.ecfg.precise
 
     # -- client ops --------------------------------------------------------
+    def _op(self, kind: int, keys, vals=None, valid=None, aux=None):
+        with _span("prism.make_op", self.dispatches):
+            return engine.make_op(kind, keys, vals, valid, aux,
+                                  value_width=self.cfg.value_width)
+
     def _dispatch(self, op: OpBatch):
-        self.estate, res = self._step(self.estate, op)
+        with _span("prism.dispatch", self.dispatches):
+            self.estate, res = self._step(self.estate, op)
         self.dispatches += 1
         return res
 
     def put(self, keys, vals=None, valid=None):
-        self._dispatch(engine.make_op(engine.PUT, keys, vals, valid,
-                                      value_width=self.cfg.value_width))
+        with _span("prism.put", self.dispatches):
+            self._dispatch(self._op(engine.PUT, keys, vals, valid))
 
     def get(self, keys, valid=None):
-        res = self._dispatch(engine.make_op(
-            engine.GET, keys, valid=valid,
-            value_width=self.cfg.value_width))
+        with _span("prism.get", self.dispatches):
+            res = self._dispatch(self._op(engine.GET, keys, valid=valid))
         return res.vals, res.found, res.src
 
     def delete(self, keys, valid=None):
-        self._dispatch(engine.make_op(engine.DELETE, keys, valid=valid,
-                                      value_width=self.cfg.value_width))
+        with _span("prism.delete", self.dispatches):
+            self._dispatch(self._op(engine.DELETE, keys, valid=valid))
 
     def scan(self, lo: int, n: int):
         return tiers.scan(self.estate.tier, jnp.int32(lo), n)
@@ -133,9 +150,9 @@ class PrismDB:
     def scan_ops(self, starts, lens, valid=None):
         """Batched bounded range scans through the fused engine step
         (YCSB-E path).  Returns per-lane live-key counts."""
-        res = self._dispatch(engine.make_op(
-            engine.SCAN, starts, valid=valid, aux=lens,
-            value_width=self.cfg.value_width))
+        with _span("prism.scan", self.dispatches):
+            res = self._dispatch(self._op(engine.SCAN, starts, valid=valid,
+                                          aux=lens))
         return res.src
 
     def run_ops(self, ops: OpBatch):
@@ -173,14 +190,22 @@ class PrismDB:
     @property
     def counters(self) -> dict:
         """Object-unit counters + derived byte counters (python ints, no
-        overflow).  This is a host readback -- introspection only, never on
-        the hot path."""
+        overflow), and with the obs plane on its compaction-burst
+        counters: ``jobs_by_trigger`` (jobs per ``TRIGGER_NAMES`` kind)
+        and ``steps_by_compactions`` (engine steps per ``step_comp_hist``
+        bucket).  This is a host readback -- introspection only, never
+        on the hot path."""
         c = tiers.counters_dict(self.estate.tier.ctr)
         vb = self.cfg.value_bytes
         c["fast_bytes_read"] = c["fast_reads"] * vb
         c["fast_bytes_written"] = c["fast_writes"] * vb
         c["slow_bytes_read"] = c["slow_reads"] * vb
         c["slow_bytes_written"] = c["slow_writes"] * vb
+        if self.ecfg.obs.enabled:
+            jobs, steps = jax.device_get((self.estate.obs.ev_jobs_t,
+                                          self.estate.obs.step_comp_hist))
+            c["jobs_by_trigger"] = [int(x) for x in jobs]
+            c["steps_by_compactions"] = [int(x) for x in steps]
         return c
 
     def occupancy(self) -> float:

@@ -198,7 +198,13 @@ def make_op(kind: int, keys: jax.Array, vals: jax.Array | None = None,
 
 
 # ------------------------------------------------------------ compaction
+# Device scopes (``jax.named_scope``) name each layer of the step in the
+# compiled program's op metadata, so a profiler trace assigns device time
+# to ``maintenance``, ``compact`` (and its phases), ``drain``,
+# ``point_ops``, ``scan_lane``, ``consolidate`` and ``obs_record``.  They
+# are metadata only: the computation is the same with or without them.
 
+@jax.named_scope("compact")
 def _compact1(state: EngineState, cfg: EngineConfig,
               mirror: MirrorFn | None,
               force_pin_keys: jax.Array | None,
@@ -253,6 +259,7 @@ def _compact1(state: EngineState, cfg: EngineConfig,
                           payload=payload, obs=obs, comp=comp)
 
 
+@jax.named_scope("compact")
 def _deep_tick(state: EngineState, cfg: EngineConfig, boundary: int,
                wm_gate, need: int = 0) -> EngineState:
     """Watermark hysteresis at one DEEP (run-to-run) boundary >= 1:
@@ -310,6 +317,7 @@ def _deep_tick(state: EngineState, cfg: EngineConfig, boundary: int,
     return state
 
 
+@jax.named_scope("maintenance")
 def maintenance(state: EngineState, cfg: EngineConfig, *,
                 need: jax.Array | int = 0,
                 wm_gate: jax.Array | bool = True,
@@ -414,6 +422,7 @@ def read_policy(state: EngineState, cfg: EngineConfig, *,
 
 # ------------------------------------------------------------ engine step
 
+@jax.named_scope("drain")
 def drain_tick(state: EngineState, cfg: EngineConfig) -> EngineState:
     """Drain one compaction quantum from the in-flight carry and log the
     resume/commit event.  No-op (not even traced) when the quantum knob
@@ -456,6 +465,7 @@ def drain_tick(state: EngineState, cfg: EngineConfig) -> EngineState:
     return state
 
 
+@jax.named_scope("consolidate")
 def _consolidation_tick(state: EngineState, cfg: EngineConfig
                         ) -> EngineState:
     """Periodic full index rebuild, as a count-gated while_loop (runs the
@@ -514,24 +524,27 @@ def engine_step(state: EngineState, op: OpBatch, cfg: EngineConfig, *,
     before = tiers.free_fast_slots(state.tier)
 
     # one masked pass for the point lanes, sharing the index lookups
-    tier, gvals, gfound, gsrc = tiers.apply_point_ops(
-        state.tier, cfg.tier, op.keys, op.vals, op.valid,
-        is_put=is_put, is_get=is_get, is_del=is_del,
-        backend=cfg.backend, interpret=cfg.interpret)
-    if cfg.compaction_quantum > 0:
-        # dual lookup: gets inside the in-flight range whose rows are
-        # not yet drained are served from the un-migrated source slots.
-        # Reads the post-op pools (a GET batch leaves them untouched;
-        # op.kind is per-batch) so the pool access chain stays serial.
-        # Drain writes are idempotent bit-equal replays, so draining
-        # before vs after this lookup cannot change any get result.
-        gvals = compaction.inflight_read(tier, state.comp, op.keys,
-                                         gvals, gfound, gsrc)
+    with jax.named_scope("point_ops"):
+        tier, gvals, gfound, gsrc = tiers.apply_point_ops(
+            state.tier, cfg.tier, op.keys, op.vals, op.valid,
+            is_put=is_put, is_get=is_get, is_del=is_del,
+            backend=cfg.backend, interpret=cfg.interpret)
+        if cfg.compaction_quantum > 0:
+            # dual lookup: gets inside the in-flight range whose rows are
+            # not yet drained are served from the un-migrated source
+            # slots.  Reads the post-op pools (a GET batch leaves them
+            # untouched; op.kind is per-batch) so the pool access chain
+            # stays serial.  Drain writes are idempotent bit-equal
+            # replays, so draining before vs after this lookup cannot
+            # change any get result.
+            gvals = compaction.inflight_read(tier, state.comp, op.keys,
+                                             gvals, gfound, gsrc)
     # scan lane: zero-length windows unless this batch is a scan
-    lens = jnp.where(is_scan, jnp.minimum(op.aux, cfg.scan_chunk), 0)
-    tier, n_live = tiers.scan_batch(tier, cfg.tier, op.keys, lens,
-                                    op.valid & is_scan,
-                                    chunk=cfg.scan_chunk)
+    with jax.named_scope("scan_lane"):
+        lens = jnp.where(is_scan, jnp.minimum(op.aux, cfg.scan_chunk), 0)
+        tier, n_live = tiers.scan_batch(tier, cfg.tier, op.keys, lens,
+                                        op.valid & is_scan,
+                                        chunk=cfg.scan_chunk)
     state = state._replace(tier=tier)
 
     if cfg.append_only:
@@ -582,10 +595,17 @@ def run_ops(state: EngineState, ops: OpBatch, cfg: EngineConfig, *,
     return lax.scan(step, state, ops)
 
 
+def _jit(base, cfg: EngineConfig, mirror: MirrorFn | None, donate: bool):
+    fn = functools.partial(base, cfg=cfg, mirror=mirror)
+    # the program keeps its function's name (``jit_engine_step``) in
+    # compiled HLO and profiler traces
+    fn.__name__ = base.__name__
+    return jax.jit(fn, donate_argnums=(0,) if donate else ())
+
+
 @functools.lru_cache(maxsize=128)
 def _cached_jit(base, cfg: EngineConfig, donate: bool):
-    fn = functools.partial(base, cfg=cfg, mirror=None)
-    return jax.jit(fn, donate_argnums=(0,) if donate else ())
+    return _jit(base, cfg, None, donate)
 
 
 def jit_step(cfg: EngineConfig, *, mirror: MirrorFn | None = None,
@@ -596,8 +616,7 @@ def jit_step(cfg: EngineConfig, *, mirror: MirrorFn | None = None,
     the same config share one compilation cache (benchmarks build many)."""
     if mirror is None:
         return _cached_jit(engine_step, cfg, donate)
-    fn = functools.partial(engine_step, cfg=cfg, mirror=mirror)
-    return jax.jit(fn, donate_argnums=(0,) if donate else ())
+    return _jit(engine_step, cfg, mirror, donate)
 
 
 def jit_run_ops(cfg: EngineConfig, *, mirror: MirrorFn | None = None,
@@ -605,5 +624,4 @@ def jit_run_ops(cfg: EngineConfig, *, mirror: MirrorFn | None = None,
     """Jitted ``run_ops`` with the state buffers donated."""
     if mirror is None:
         return _cached_jit(run_ops, cfg, donate)
-    fn = functools.partial(run_ops, cfg=cfg, mirror=mirror)
-    return jax.jit(fn, donate_argnums=(0,) if donate else ())
+    return _jit(run_ops, cfg, mirror, donate)

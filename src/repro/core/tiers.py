@@ -491,12 +491,14 @@ def apply_point_ops(state: TierState, cfg: TierConfig, keys: jax.Array,
     # ---- tracker --------------------------------------------------------
     trk_locs = jnp.where(shit_any, 1, 0).astype(jnp.int8)
     trk_mask = putk | (g & found)
-    if backend == "reference":
-        trk = tracker.access_batched(state.tracker, keys, trk_locs, trk_mask)
-    else:
-        from repro.kernels.clock_update.ops import tracker_access
-        trk = tracker_access(state.tracker, keys, trk_locs, trk_mask,
-                             backend=backend, interpret=interpret)
+    with jax.named_scope("tracker"):
+        if backend == "reference":
+            trk = tracker.access_batched(state.tracker, keys, trk_locs,
+                                         trk_mask)
+        else:
+            from repro.kernels.clock_update.ops import tracker_access
+            trk = tracker_access(state.tracker, keys, trk_locs, trk_mask,
+                                 backend=backend, interpret=interpret)
 
     # ---- counters -------------------------------------------------------
     n_put = cnt(putk)

@@ -117,7 +117,7 @@ def clock_update(trk_keys, trk_clock, trk_loc, slots, keys, occ, locs, *,
         in_specs=[batch, batch, batch, table, table, table],
         out_specs=[table, table, table],
         out_shape=[jax.ShapeDtypeStruct((1, t), jnp.int32)] * 3,
-        interpret=interpret,
+        interpret=interpret, name="clock_update",
     )(col(slots, -1), col(keys, -1), col(pay, -1), row(trk_keys),
       row(trk_clock), row(trk_loc))
     tk, tc, tl = (x[0] for x in out)

@@ -66,7 +66,7 @@ def msc_scores(lo, hi, t_f, bucket_fast, bucket_slow, bucket_overlap, bhist,
         + [full((4, nb)), full((1, 4))],
         out_specs=full((k, 1)),
         out_shape=jax.ShapeDtypeStruct((k, 1), jnp.float32),
-        interpret=interpret,
+        interpret=interpret, name="msc_score",
     )(col(lo), col(hi), col(t_f), row(bucket_fast), row(bucket_slow),
       row(bucket_overlap), bhist.T, row(probs.astype(jnp.float32)))
     return out[:, 0]

@@ -65,14 +65,15 @@ def _gather_block(pick_src, idx_ref, out_ref, buf, sem):
         out_ref[j:j + 1, :] = buf[j, pl.ds(row % ROWS, 1), :]
 
 
-def _mover(kernel, n_prefetch: int, in_specs, out_spec, out_shape, m: int,
-           scratch, interpret: bool, **kw):
+def _mover(kernel, name: str, n_prefetch: int, in_specs, out_spec,
+           out_shape, m: int, scratch, interpret: bool, **kw):
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=n_prefetch, grid=(m // ROWS,),
             in_specs=in_specs, out_specs=out_spec, scratch_shapes=scratch),
-        out_shape=out_shape, interpret=interpret, **kw)
+        out_shape=out_shape, interpret=interpret,
+        name=f"tier_compact_{name}", **kw)
 
 
 def _pad_rows(x, mult: int = ROWS):
@@ -95,7 +96,8 @@ def gather_rows(pool, idx, *, interpret: bool = False):
         _gather_block(lambda i: [(None, pool_ref)], idx_ref, out_ref, buf,
                       sem)
 
-    out = _mover(kernel, 1, [pl.BlockSpec(memory_space=pl.ANY)],
+    out = _mover(kernel, "gather_rows", 1,
+                 [pl.BlockSpec(memory_space=pl.ANY)],
                  pl.BlockSpec((ROWS, w), lambda g, idx: (g, 0)),
                  jax.ShapeDtypeStruct((idx_p.shape[0], w), pool.dtype),
                  idx_p.shape[0], _gather_scratch(w, pool.dtype), interpret,
@@ -121,7 +123,8 @@ def select_gather_rows(fast_pool, slow_pool, src_slow, idx, *,
                                  (pid_ref[i] != 0, slow_ref)],
                       idx_ref, out_ref, buf, sem)
 
-    out = _mover(kernel, 2, [pl.BlockSpec(memory_space=pl.ANY)] * 2,
+    out = _mover(kernel, "select_gather_rows", 2,
+                 [pl.BlockSpec(memory_space=pl.ANY)] * 2,
                  pl.BlockSpec((ROWS, w), lambda g, pid, idx: (g, 0)),
                  jax.ShapeDtypeStruct((idx_p.shape[0], w), fast_pool.dtype),
                  idx_p.shape[0], _gather_scratch(w, fast_pool.dtype),
@@ -156,7 +159,7 @@ def scatter_rows(pool, idx, rows, valid, *, interpret: bool = False):
     distinct slots) and in [0, P); invalid entries are skipped."""
     p, w = pool.shape
     rows_p = _pad_rows(rows)
-    out = _mover(_scatter_kernel, 2,
+    out = _mover(_scatter_kernel, "scatter_rows", 2,
                  [pl.BlockSpec((ROWS, w), lambda g, idx, v: (g, 0)),
                   pl.BlockSpec(memory_space=pl.ANY)],
                  pl.BlockSpec(memory_space=pl.ANY),

@@ -4,11 +4,11 @@ from repro.obs.cost import (CostModel, TierCost, boundary_io_us,
 from repro.obs.export import (bucket_bounds, bucket_of_us_np, events_table,
                               hist_delta, hist_sum_delta,
                               quantile_from_hist, quantiles_from_hist,
-                              snapshot, timeline_table, to_records,
-                              write_jsonl)
+                              snapshot, timeline_table)
 from repro.obs.profile import maybe_trace
 from repro.obs.state import (EV_COMMIT, EV_RESUME, EV_START,
-                             EVENT_KIND_NAMES, KIND_NAMES, N_KINDS, TICK,
+                             EVENT_KIND_NAMES, KIND_NAMES, N_KINDS,
+                             STEP_COMP_BUCKETS, TICK,
                              TRIG_POLICY, TRIG_RATE_LIMIT, TRIG_WATERMARK,
                              TRIGGER_NAMES, ObsConfig, ObsState,
                              bucket_of_us, counter_delta, init,
@@ -29,9 +29,9 @@ __all__ = [
     "drain_io_us", "step_io_us",
     "bucket_bounds", "bucket_of_us_np", "events_table", "hist_delta",
     "hist_sum_delta", "quantile_from_hist", "quantiles_from_hist",
-    "snapshot", "timeline_table", "to_records", "write_jsonl",
+    "snapshot", "timeline_table",
     "maybe_trace", "EV_COMMIT", "EV_RESUME", "EV_START",
-    "EVENT_KIND_NAMES", "KIND_NAMES", "N_KINDS", "TICK",
+    "EVENT_KIND_NAMES", "KIND_NAMES", "N_KINDS", "STEP_COMP_BUCKETS", "TICK",
     "TIMELINE_FIELDS", "TRIG_POLICY", "TRIG_RATE_LIMIT", "TRIG_WATERMARK",
     "TRIGGER_NAMES", "ObsConfig", "ObsState", "bucket_of_us",
     "counter_delta", "init", "record_compaction", "record_drain",

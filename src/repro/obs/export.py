@@ -2,20 +2,18 @@
 
 Everything here runs OUTSIDE jit, at segment boundaries: one
 ``jax.device_get`` pulls the whole (small, fixed-size) pytree, then
-plain numpy turns it into structured dicts, percentile estimates, and
-JSON-lines.  The numpy bucket function is a bit-exact mirror of the
+plain numpy turns it into structured dicts and percentile estimates.
+The numpy bucket function is a bit-exact mirror of the
 device one so the quantile tests can use an exact oracle.
 """
 from __future__ import annotations
 
-import json
-from typing import Iterable, Mapping, Sequence
+from typing import Mapping, Sequence
 
 import jax
 import numpy as np
 
-from repro.obs.state import (EVENT_KIND_NAMES, KIND_NAMES, N_KINDS,
-                             TRIGGER_NAMES, ObsState)
+from repro.obs.state import EVENT_KIND_NAMES, TRIGGER_NAMES, ObsState
 
 QUANTILES = (0.5, 0.99, 0.999)
 QUANTILE_NAMES = {0.5: "p50", 0.99: "p99", 0.999: "p999"}
@@ -114,6 +112,8 @@ def snapshot(obs: ObsState) -> dict:
     hist_sum = np.asarray(host.hist_sum)
     ev_jobs = np.asarray(host.ev_jobs).reshape(-1)
     ev_jobs_b = np.asarray(host.ev_jobs_b)
+    ev_jobs_t = np.asarray(host.ev_jobs_t)
+    step_comp_hist = np.asarray(host.step_comp_hist)
     snap = {
         "hist": hist.sum(axis=0) if stacked else hist,
         "hist_sum": hist_sum.sum(axis=0) if stacked else hist_sum,
@@ -133,6 +133,9 @@ def snapshot(obs: ObsState) -> dict:
         "ev_boundary": np.asarray(host.ev_boundary),
         "ev_jobs_b": (ev_jobs_b.sum(axis=0) if ev_jobs_b.ndim == 2
                       else ev_jobs_b),
+        "ev_jobs_t": ev_jobs_t.sum(axis=0) if stacked else ev_jobs_t,
+        "step_comp_hist": (step_comp_hist.sum(axis=0) if stacked
+                           else step_comp_hist),
         "n_partitions": hist.shape[0] if stacked else 1,
     }
     return snap
@@ -230,40 +233,3 @@ def timeline_table(snap: Mapping) -> list:
                              else sum(vec))
             rows.append(row)
     return rows
-
-
-def to_records(snap: Mapping, meta: Mapping | None = None) -> Iterable[dict]:
-    """Flatten a snapshot into JSON-able records (one per line in the
-    JSONL export): a meta header, one histogram record per op kind plus
-    the total, then timeline and compaction-event rows."""
-    yield {"record": "meta", "t_pos": snap["t_pos"],
-           "ev_count": snap["ev_count"],
-           "n_partitions": snap.get("n_partitions", 1),
-           **dict(meta or {})}
-    hist = np.asarray(snap["hist"])
-    sums = (np.asarray(snap["hist_sum"]) if "hist_sum" in snap
-            else None)
-    for k in range(N_KINDS):
-        if hist[k].sum() == 0:
-            continue
-        yield {"record": "hist", "kind": KIND_NAMES[k],
-               "counts": hist[k].tolist(),
-               **quantiles_from_hist(
-                   hist[k], sums=None if sums is None else sums[k])}
-    yield {"record": "hist", "kind": "total",
-           "counts": hist.sum(axis=0).tolist(),
-           **quantiles_from_hist(hist, sums=sums)}
-    for row in timeline_table(snap):
-        yield {"record": "step", **row}
-    for row in events_table(snap):
-        yield {"record": "compaction", **row}
-
-
-def write_jsonl(path, snap: Mapping, meta: Mapping | None = None) -> int:
-    """Write the snapshot as JSON-lines; returns the record count."""
-    n = 0
-    with open(path, "w") as fh:
-        for rec in to_records(snap, meta):
-            fh.write(json.dumps(rec) + "\n")
-            n += 1
-    return n

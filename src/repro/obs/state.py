@@ -3,7 +3,7 @@
 ``ObsState`` rides inside ``EngineState`` so every metric below is
 maintained INSIDE the jitted hot loop -- zero extra dispatches, zero
 host syncs; the host only ever reads it back at segment boundaries
-(``repro.obs.export``).  Three instruments:
+(``repro.obs.export``).  Four instruments:
 
   * ``hist``      -- log2-bucketed histograms of the modeled per-op
                      service cost (Table-1 constants, ``repro.obs.cost``),
@@ -21,6 +21,10 @@ host syncs; the host only ever reads it back at segment boundaries
                      kind (rate-limit / watermark / §5.3 policy), the
                      selected range's MSC score, objects moved and
                      superseded, and the compaction's modeled I/O.
+  * burst counters -- ``ev_jobs_t`` (jobs per trigger kind) and
+                     ``step_comp_hist`` (steps by the compactions they
+                     committed): how bursty compaction is, over every
+                     step, where the ring keeps only the last events.
 
 Every update is a masked scatter-add / scatter-set with computed
 indices: no ``lax.cond`` over state, so the PR 4 branchless-hot-loop
@@ -59,6 +63,11 @@ TRIGGER_NAMES = ("rate_limit", "watermark", "policy")
 # that quantum's io_us; the final quantum's entry is the "commit".
 EV_COMMIT, EV_START, EV_RESUME = 0, 1, 2
 EVENT_KIND_NAMES = ("commit", "start", "resume")
+
+# per-step compaction-burst histogram: bucket 0 holds steps that committed
+# no compaction, bucket b holds [2^(b-1), 2^b), the last everything from
+# 256 (engine.max_rounds) up
+STEP_COMP_BUCKETS = 10
 
 # timeline row layout: [kind, n_ops, *flattened Counters deltas] --
 # per-tier vector counters expand to one column per entry ("hits0",
@@ -134,6 +143,12 @@ class ObsState(NamedTuple):
     ev_jobs_b: jax.Array     # i32[n_boundaries] jobs per boundary
                              # (sums to ev_jobs; conservation oracle:
                              # ev_jobs_b[b] == ctr.comp_by_boundary[b])
+    ev_jobs_t: jax.Array     # i32[3] jobs per TRIG_* kind (sums to
+                             # ev_jobs): how much of compaction the
+                             # watermark hysteresis runs
+    step_comp_hist: jax.Array  # i32[STEP_COMP_BUCKETS] engine steps by
+                             # the compactions committed in the step
+                             # (sums to t_pos): the burst a tail step ran
 
 
 def init(cfg: ObsConfig) -> ObsState:
@@ -156,6 +171,8 @@ def init(cfg: ObsConfig) -> ObsState:
         ev_jobs=jnp.zeros((), jnp.int32),
         ev_boundary=jnp.zeros((e,), jnp.int32),
         ev_jobs_b=jnp.zeros((cfg.n_boundaries,), jnp.int32),
+        ev_jobs_t=jnp.zeros((len(TRIGGER_NAMES),), jnp.int32),
+        step_comp_hist=jnp.zeros((STEP_COMP_BUCKETS,), jnp.int32),
     )
 
 
@@ -179,6 +196,15 @@ def counter_delta(after: Counters, before: Counters) -> Counters:
     return jax.tree.map(lambda a, b: a - b, after, before)
 
 
+def step_comp_bucket(n: jax.Array) -> jax.Array:
+    """``step_comp_hist`` bucket of a step that committed ``n >= 0``
+    compactions: n's bit length, so 0 -> 0, 1 -> 1, 2-3 -> 2, ...,
+    capped at the last bucket."""
+    n = jnp.asarray(n, jnp.int32)
+    return jnp.minimum(32 - jax.lax.clz(n), STEP_COMP_BUCKETS - 1)
+
+
+@jax.named_scope("obs_record")
 def record_step(obs: ObsState, cfg: ObsConfig, *, kind: jax.Array,
                 n_ops: jax.Array, delta: Counters) -> ObsState:
     """Fold one engine step's counter deltas into the histograms and the
@@ -189,7 +215,8 @@ def record_step(obs: ObsState, cfg: ObsConfig, *, kind: jax.Array,
     the tail the paper's headline claim is about.
 
     Branchless: one scatter-add into ``hist[kind, bucket]`` weighted by
-    the batch's valid-op count, one scatter-set of the timeline row."""
+    the batch's valid-op count, one into ``step_comp_hist`` at the
+    step's compaction count, one scatter-set of the timeline row."""
     n_ops = jnp.asarray(n_ops, jnp.int32)
     us = step_io_us(delta, cfg.cost, cfg.fast_write_amp)
     per_op = us / jnp.maximum(n_ops.astype(jnp.float32), 1.0)
@@ -201,10 +228,13 @@ def record_step(obs: ObsState, cfg: ObsConfig, *, kind: jax.Array,
         [jnp.stack([jnp.asarray(kind, jnp.int32), n_ops])]
         + [jnp.atleast_1d(jnp.asarray(v, jnp.int32)) for v in delta])
     timeline = obs.timeline.at[obs.t_pos % cfg.timeline_len].set(row)
+    step_comp_hist = obs.step_comp_hist.at[
+        step_comp_bucket(delta.compactions)].add(1)
     return obs._replace(hist=hist, hist_sum=hist_sum, timeline=timeline,
-                        t_pos=obs.t_pos + 1)
+                        t_pos=obs.t_pos + 1, step_comp_hist=step_comp_hist)
 
 
+@jax.named_scope("obs_record")
 def record_compaction(obs: ObsState, cfg: ObsConfig, *, step: jax.Array,
                       trigger: jax.Array,
                       stats: "CompactionStats",  # noqa: F821
@@ -218,8 +248,8 @@ def record_compaction(obs: ObsState, cfg: ObsConfig, *, step: jax.Array,
     Run-to-completion keeps the defaults: one EV_COMMIT per job pricing
     the whole migration.  The quantized path logs the trigger as an
     EV_START with ``io_us=0.0`` (the step defers its migration cost into
-    the in-flight carry); ``new_job`` counts jobs (``ev_jobs``)
-    independently of ring entries."""
+    the in-flight carry); ``new_job`` counts jobs (``ev_jobs``, and by
+    trigger kind ``ev_jobs_t``) independently of ring entries."""
     i = obs.ev_count % cfg.event_len
     moved = stats.n_demoted + stats.n_promoted + stats.n_merged
     if io_us is None:
@@ -239,9 +269,11 @@ def record_compaction(obs: ObsState, cfg: ObsConfig, *, step: jax.Array,
         ev_boundary=obs.ev_boundary.at[i].set(jnp.int32(boundary)),
         ev_count=obs.ev_count + 1,
         ev_jobs=obs.ev_jobs + (1 if new_job else 0),
-        ev_jobs_b=obs.ev_jobs_b.at[boundary].add(1 if new_job else 0))
+        ev_jobs_b=obs.ev_jobs_b.at[boundary].add(1 if new_job else 0),
+        ev_jobs_t=obs.ev_jobs_t.at[trigger].add(1 if new_job else 0))
 
 
+@jax.named_scope("obs_record")
 def record_drain(obs: ObsState, cfg: ObsConfig, *, step: jax.Array,
                  trigger: jax.Array, score: jax.Array, moved: jax.Array,
                  io_us: jax.Array, done: jax.Array) -> ObsState:

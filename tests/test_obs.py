@@ -11,6 +11,7 @@ property a histogram supports, and it is checked exactly.
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 try:                                   # property tests need hypothesis;
     from hypothesis import given, settings      # everything else runs
@@ -22,9 +23,9 @@ except ImportError:
 from repro.core import PrismDB, TierConfig, compaction, tiers
 from repro.obs import (ObsConfig, bucket_bounds, bucket_of_us,
                        bucket_of_us_np, events_table, quantile_from_hist,
-                       quantiles_from_hist, snapshot, timeline_table,
-                       to_records)
+                       quantiles_from_hist, snapshot, timeline_table)
 from repro.obs import state as obs_state
+from repro.workloads.reference import zipf_keys_host
 
 CFG = TierConfig(key_space=512, fast_slots=64, slow_slots=1024,
                  value_width=1, max_runs=32, run_size=32,
@@ -230,24 +231,57 @@ def test_partitioned_db_merged_snapshot():
     assert snap["ev_count"] == sum(db.counters["compactions"])
 
 
-# ----------------------------------------------------------- exporter
+# ------------------------------------------- compaction-burst counters
 
-def test_jsonl_records_roundtrip(tmp_path):
-    import json
+# three tiers: a small middle tier, so deep (watermark) merges run too
+CFG3 = TierConfig(key_space=1 << 11, fast_slots=128, slow_slots=1 << 10,
+                  value_width=2, max_runs=32, run_size=64,
+                  bloom_bits_per_run=1 << 12, tracker_slots=1 << 9,
+                  n_buckets=32, pin_threshold=0.1,
+                  tier_slots=(128, 256, 1 << 10))
 
-    from repro.obs import write_jsonl
-    db = PrismDB(CFG, seed=0)
-    db.put(np.arange(100, dtype=np.int32))
-    db.get(np.arange(50, dtype=np.int32))
+
+def test_step_comp_bucket_is_the_bit_length_capped():
+    n = np.array([0, 1, 2, 3, 4, 7, 8, 127, 128, 255, 256, 300, 4096])
+    want = np.minimum([int(x).bit_length() for x in n],
+                      obs_state.STEP_COMP_BUCKETS - 1)
+    np.testing.assert_array_equal(
+        np.asarray(obs_state.step_comp_bucket(jnp.asarray(n))), want)
+
+
+@pytest.mark.parametrize("mix", ["A", "C"])
+@pytest.mark.parametrize("cfg,quantum", [(CFG, 0), (CFG, 3), (CFG3, 0),
+                                         (CFG3, 3)],
+                         ids=["N2-q0", "N2-q3", "N3-q0", "N3-q3"])
+def test_burst_counters_conserve(mix, cfg, quantum):
+    """Through ``PrismDB``: jobs by trigger sum to the jobs and to the
+    compactions, steps by compactions to the steps, and every step's
+    bucket matches its timeline row's ``compactions``."""
+    db = PrismDB(cfg, seed=0, compaction_quantum=quantum)
+    rng = np.random.default_rng(11)
+    batch = 64
+    for keys in rng.permutation(cfg.key_space).astype(np.int32).reshape(
+            -1, batch):                         # load every key once
+        db.put(keys)
+    for t in range(24):                         # YCSB-A or YCSB-C
+        keys = zipf_keys_host(rng, 0.99, batch, cfg.key_space).astype(
+            np.int32)
+        if mix == "A" and t % 2:
+            db.put(keys)
+        else:
+            db.get(keys)
     snap = db.obs_snapshot()
-    path = tmp_path / "obs.jsonl"
-    n = write_jsonl(path, snap, meta={"run": "unit"})
-    lines = [json.loads(l) for l in path.read_text().splitlines()]
-    assert len(lines) == n
-    assert lines[0]["record"] == "meta" and lines[0]["run"] == "unit"
-    kinds = {l["record"] for l in lines}
-    assert {"meta", "hist", "step"} <= kinds
-    tot = [l for l in lines if l["record"] == "hist"
-           and l["kind"] == "total"][0]
-    assert sum(tot["counts"]) == 150
-    assert set(to_records(snap).__next__().keys()) >= {"record", "t_pos"}
+    ctr = db.counters
+    assert ctr["compactions"] > 0
+    assert int(snap["ev_jobs_t"].sum()) == snap["ev_jobs"] \
+        == ctr["compactions"]
+    assert ctr["jobs_by_trigger"] == snap["ev_jobs_t"].tolist()
+    assert int(snap["step_comp_hist"].sum()) == snap["t_pos"]
+    assert ctr["steps_by_compactions"] == snap["step_comp_hist"].tolist()
+    rows = timeline_table(snap)
+    assert len(rows) == snap["t_pos"] == db.dispatches   # ring not wrapped
+    buckets = [min(r["compactions"].bit_length(),
+                   obs_state.STEP_COMP_BUCKETS - 1) for r in rows]
+    np.testing.assert_array_equal(
+        snap["step_comp_hist"],
+        np.bincount(buckets, minlength=obs_state.STEP_COMP_BUCKETS))

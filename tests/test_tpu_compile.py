@@ -125,3 +125,41 @@ def test_pallas_ycsb_a_segment_compiles(one_chip):
         state, gen, rng, sched, t0=t0).compile().as_text()
     # the tracker update (every step) and the MSC scorer (every compaction)
     assert text.count(KERNEL) >= 2
+
+
+# every jax.named_scope of the engine step (drain and consolidate are
+# traced only with a compaction quantum and a consolidation period)
+SCOPES = ("maintenance", "compact", "select", "demote", "merge",
+          "write_runs", "slow_index", "blooms", "stats", "drain",
+          "point_ops", "tracker", "scan_lane", "consolidate", "obs_record")
+
+
+def test_engine_step_names_its_scopes_and_kernels(one_chip):
+    """The pallas engine step, compiled for the chip, keeps every device
+    scope in its ops' ``op_name`` metadata (a trace of the chip carries
+    only instruction names, which this metadata maps to scopes), its
+    program's name, and its kernels' names."""
+    import re
+
+    from repro.core import TierConfig, engine
+    cfg = TierConfig(key_space=1 << 14, fast_slots=2048,
+                     slow_slots=1 << 14, value_width=256, max_runs=64,
+                     run_size=256, bloom_bits_per_run=1 << 12,
+                     tracker_slots=1638, n_buckets=128)
+    ecfg = engine.EngineConfig(tier=cfg, backend="pallas", interpret=False,
+                               compaction_quantum=64, consolidate_every=8)
+    state = one_chip(jax.eval_shape(functools.partial(engine.init, ecfg),
+                                    jax.random.PRNGKey(0)))
+    op = one_chip(jax.eval_shape(lambda: engine.make_op(
+        engine.PUT, jnp.zeros((256,), jnp.int32),
+        value_width=cfg.value_width)))
+    text = engine.jit_step(ecfg).lower(state, op).compile().as_text()
+    assert text.startswith("HloModule jit_engine_step")
+    stacks = {p for path in re.findall(r'op_name="([^"]*)"', text)
+              for p in path.split("/")}
+    assert set(SCOPES) <= stacks, set(SCOPES) - stacks
+    kernels = set(re.findall(r"%([a-z_]+)(?:\.\d+)? = [^\n]*" + KERNEL,
+                             text))
+    assert kernels == {"clock_update", "msc_score",
+                       "tier_compact_select_gather_rows",
+                       "tier_compact_scatter_rows"}, kernels
