@@ -15,9 +15,10 @@ Building blocks (all jit-/vmap-/scan-safe, static shapes):
                     append-only virtual fill + an arbitrary ``payload``
                     pytree mirrored through compactions (KV pages,
                     embedding rows; ``()`` when the store is metadata-only)
-  ``engine_step``   one client batch, BRANCHLESS: every op kind flows
+  ``engine_step``   one client batch: every point op kind flows
                     through one masked structure-of-arrays pass
-                    (``tiers.apply_point_ops`` + a masked scan lane), and
+                    (``tiers.apply_point_ops``), the scan lane is a
+                    kind-gated ``lax.cond`` that returns counts only, and
                     the maintenance plane is gated ``lax.while_loop``s.
                     No ``lax.switch``/``lax.cond`` ever carries pool-sized
                     state: on XLA CPU each such branch materializes an
@@ -490,16 +491,19 @@ def engine_step(state: EngineState, op: OpBatch, cfg: EngineConfig, *,
                 ) -> tuple[EngineState, OpResult]:
     """One client batch, control plane included: a single dispatch.
 
-    Branchless: ``op.kind`` is a traced scalar turned into lane masks, so
-    one compiled body serves put/get/delete/scan -- inside the workload
-    ``lax.scan`` no per-kind branch exists to materialize pool-sized
-    copies, and a single compilation covers every op stream.
+    ``op.kind`` is a traced scalar, so one compiled body serves
+    put/get/delete/scan and a single compilation covers every op stream.
+    The point ops are branchless: the kind becomes lane masks of one
+    pass.  The scan lane is kind-gated: a ``lax.cond`` runs it only on a
+    scan batch.  Its branch only reads the indexes and returns per-lane
+    counts, so nothing pool-shaped goes through it and no branch
+    materializes a pool-sized copy inside the workload ``lax.scan``.
 
     The maintenance plane runs as ONE loop before the data op: the §4.2
     rate limit frees this batch's write headroom, the watermark
     hysteresis (armed at every step boundary -- the async job drains the
     previous put's overflow at the next step), and the §5.3 budget for
-    read batches.  Then the masked point-op pass + the scan lane, and
+    read batches.  Then the masked point-op pass, the scan lane, and
     append-only virtual-fill accounting on put batches.
     """
     is_put = op.kind == PUT
@@ -539,12 +543,24 @@ def engine_step(state: EngineState, op: OpBatch, cfg: EngineConfig, *,
             # change any get result.
             gvals = compaction.inflight_read(tier, state.comp, op.keys,
                                              gvals, gfound, gsrc)
-    # scan lane: zero-length windows unless this batch is a scan
-    with jax.named_scope("scan_lane"):
-        lens = jnp.where(is_scan, jnp.minimum(op.aux, cfg.scan_chunk), 0)
-        tier, n_live = tiers.scan_batch(tier, cfg.tier, op.keys, lens,
-                                        op.valid & is_scan,
-                                        chunk=cfg.scan_chunk)
+    # scan lane, kind-gated: only a scan batch runs it.  The branch reads
+    # the indexes, fast_ver and tombs and returns per-lane counts alone
+    # (nothing pool-shaped goes through it); the counters are charged
+    # outside it, zero lanes on any other batch
+    scanning = op.valid & is_scan
+    lens = jnp.where(scanning, jnp.clip(op.aux, 0, cfg.scan_chunk), 0)
+
+    def lanes():
+        with jax.named_scope("scan_lane"):
+            return tiers.scan_lane_counts(tier, op.keys, lens,
+                                          chunk=cfg.scan_chunk)
+
+    def idle():
+        return (jnp.zeros(lens.shape, jnp.int32),
+                jnp.zeros(lens.shape + (len(tier.keys),), jnp.int32))
+
+    n_live, per_tier = lax.cond(is_scan, lanes, idle)
+    tier = tiers.count_scans(tier, scanning, per_tier)
     state = state._replace(tier=tier)
 
     if cfg.append_only:

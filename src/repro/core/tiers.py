@@ -568,8 +568,8 @@ def delete_batch(state: TierState, cfg: TierConfig, keys: jax.Array,
 
 
 def _scan_windows(state: TierState, lo: jax.Array, take: int) -> tuple:
-    """The merged-scan core shared by ``scan`` and ``scan_batch``: the
-    next ``take`` index entries >= ``lo`` from EACH tier, with
+    """The merged-scan core shared by ``scan`` and ``scan_lane_counts``:
+    the next ``take`` index entries >= ``lo`` from EACH tier, with
     tombstoned entries and upper-tier-shadowed lower entries masked to
     PADKEY.  Returns one key window per tier, hottest first."""
     ar = jnp.arange(take)
@@ -606,19 +606,14 @@ def scan(state: TierState, lo: jax.Array, n: int) -> tuple[jax.Array,
     return keys, keys != PADKEY
 
 
-def scan_batch(state: TierState, cfg: TierConfig, starts: jax.Array,
-               lens: jax.Array, valid: jax.Array, *, chunk: int
-               ) -> tuple[TierState, jax.Array]:
-    """Batched bounded range scans (YCSB-E) over the merged sorted indexes.
-
-    Per lane: up to ``lens[b]`` live keys >= ``starts[b]`` in sorted order,
-    window-bounded by ``chunk`` index entries per tier.  Returns
-    ``(state', n_live)`` where ``n_live[b]`` counts the keys the scan
-    returned (also totaled in ``scan_objs``).  I/O accounting: every
-    returned object is charged a read on its tier; run-structured-tier
-    scan reads are sequential (runs are key-sorted), so they also land
-    in that tier's ``scan_reads`` entry for the cost model.
-    """
+def scan_lane_counts(state: TierState, starts: jax.Array, lens: jax.Array,
+                     *, chunk: int) -> tuple[jax.Array, jax.Array]:
+    """The scan lane's work: per lane, how many of the live keys >=
+    ``starts[b]`` it returns (up to ``lens[b]``, window-bounded by
+    ``chunk`` index entries per tier), in all (``i32[B]``) and per tier
+    (``i32[B, T]``).  Reads only the indexes, ``fast_ver`` and
+    ``tombs``, and returns counts alone, so it can sit in a branch
+    without carrying pool state through it."""
     n_tiers = len(state.keys)
 
     def one(lo, ln):
@@ -635,8 +630,16 @@ def scan_batch(state: TierState, cfg: TierConfig, starts: jax.Array,
              for t in range(n_tiers)])
         return jnp.sum(sel.astype(jnp.int32)), per_tier
 
-    ln = jnp.where(valid, jnp.maximum(lens, 0), 0)
-    n_live, per_tier = jax.vmap(one)(starts, ln)
+    return jax.vmap(one)(starts, lens)
+
+
+def count_scans(state: TierState, valid: jax.Array, per_tier: jax.Array
+                ) -> TierState:
+    """Charge a scan batch's I/O: ``valid`` lanes to ``scans``, every
+    returned object (``per_tier``, ``i32[B, T]``) a read on its tier;
+    run-structured-tier scan reads are sequential (runs are key-sorted),
+    so they also land in that tier's ``scan_reads`` entry for the cost
+    model."""
     tier_tot = jnp.sum(per_tier, axis=0)        # i32[T]
     seq_tot = tier_tot.at[0].set(0)             # tier-0 reads are random
     ctr = state.ctr._replace(
@@ -645,7 +648,23 @@ def scan_batch(state: TierState, cfg: TierConfig, starts: jax.Array,
         reads=state.ctr.reads + tier_tot,
         scan_reads=state.ctr.scan_reads + seq_tot,
     )
-    return state._replace(ctr=ctr), n_live
+    return state._replace(ctr=ctr)
+
+
+def scan_batch(state: TierState, cfg: TierConfig, starts: jax.Array,
+               lens: jax.Array, valid: jax.Array, *, chunk: int
+               ) -> tuple[TierState, jax.Array]:
+    """Batched bounded range scans (YCSB-E) over the merged sorted indexes.
+
+    Per lane: up to ``lens[b]`` live keys >= ``starts[b]`` in sorted order,
+    window-bounded by ``chunk`` index entries per tier.  Returns
+    ``(state', n_live)`` where ``n_live[b]`` counts the keys the scan
+    returned (also totaled in ``scan_objs``), charged as ``count_scans``
+    says.
+    """
+    ln = jnp.where(valid, jnp.maximum(lens, 0), 0)
+    n_live, per_tier = scan_lane_counts(state, starts, ln, chunk=chunk)
+    return count_scans(state, valid, per_tier), n_live
 
 
 # ------------------------------------------------------- host-side export

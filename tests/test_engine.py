@@ -258,6 +258,70 @@ def test_scan_matches_bruteforce_reference_with_tombstones():
         assert not (set(got.tolist()) & set(victims.tolist()))
 
 
+# three tiers: a small middle tier, so deep merges run too
+CFG3 = TierConfig(key_space=1 << 11, fast_slots=128, slow_slots=1 << 10,
+                  value_width=2, max_runs=32, run_size=64,
+                  bloom_bits_per_run=1 << 12, tracker_slots=1 << 9,
+                  n_buckets=32, pin_threshold=0.1,
+                  tier_slots=(128, 256, 1 << 10))
+
+
+@pytest.mark.parametrize("backend", ["reference", "pallas"])
+@pytest.mark.parametrize("cfg,quantum", [(CFG, 0), (CFG, 3), (CFG3, 0),
+                                         (CFG3, 3)],
+                         ids=["N2-q0", "N2-q3", "N3-q0", "N3-q3"])
+def test_kind_gated_scan_lane_is_bit_identical(cfg, quantum, backend):
+    """Through the kind-gated ``engine_step`` over a seeded stream of
+    put/get/delete/scan batches: a scan step's results and whole tier
+    state (every counter included) are ``tiers.scan_batch``'s on the
+    state its lane reads (the same step with every lane invalid), and
+    any other step leaves the scan counters unchanged."""
+    db = PrismDB(cfg, seed=0, backend=backend, compaction_quantum=quantum)
+    chunk = db.ecfg.scan_chunk
+    step = engine.jit_step(db.ecfg, donate=False)
+    lanes = jax.jit(lambda t, k, n, v: tiers.scan_batch(
+        t, cfg, k, n, v, chunk=chunk))
+    state = db.estate
+    rng = np.random.default_rng(7)
+    batch, n_keys = 64, 600
+    kinds = [engine.PUT] * 8 + list(rng.choice(
+        [engine.PUT, engine.GET, engine.DELETE, engine.SCAN], 40,
+        p=[0.3, 0.25, 0.15, 0.3]))
+    for t, kind in enumerate(kinds):
+        keys = rng.choice(n_keys, batch, replace=False).astype(np.int32)
+        valid = rng.random(batch) < (1.0 if t < 8 else 0.8)
+        aux = rng.integers(-4, chunk + 8, batch).astype(np.int32)
+        vals = np.stack([keys, np.full(batch, t)], 1).astype(np.float32)
+        op = engine.make_op(int(kind), jnp.asarray(keys), jnp.asarray(vals),
+                            valid=jnp.asarray(valid), aux=jnp.asarray(aux),
+                            value_width=cfg.value_width)
+        new, res = step(state, op)
+        src, found = np.asarray(res.src), np.asarray(res.found)
+        if kind == engine.SCAN:
+            before, _ = step(state, op._replace(
+                valid=jnp.zeros(batch, bool)))
+            want, n_live = lanes(before.tier, op.keys,
+                                 jnp.minimum(op.aux, chunk), op.valid)
+            assert all(map(np.array_equal,
+                           *map(jax.tree.leaves, jax.device_get(
+                               (new.tier, want)))))
+            np.testing.assert_array_equal(src, np.asarray(n_live))
+            np.testing.assert_array_equal(found, np.asarray(n_live) > 0)
+        else:
+            for name in ("scans", "scan_objs", "scan_reads"):
+                np.testing.assert_array_equal(
+                    np.asarray(getattr(new.tier.ctr, name)),
+                    np.asarray(getattr(state.tier.ctr, name)), name)
+            if kind != engine.GET:
+                assert (src == -1).all() and not found.any()
+        if kind != engine.GET:
+            assert not np.asarray(res.vals).any()
+        state = new
+    ctr = tiers.counters_dict(state.tier.ctr)
+    assert ctr["scan_objs"] > 0 and ctr["gets"] > 0
+    assert ctr["compactions"] > 0
+
+
 def test_scan_excludes_every_deleted_key():
     db = PrismDB(CFG, seed=1)
     for i in range(0, 400, 100):                # forces demotions

@@ -84,6 +84,28 @@ def _unbounded_while_bodies(hlo: str) -> set[str]:
     return out
 
 
+def every_step_blocks(hlo: str) -> set[str]:
+    """Computations that run whenever their caller does: those reachable
+    from the entry computation through calls, fusions, reductions and
+    while loops, without entering a branch of a ``conditional``."""
+    blocks = _blocks(hlo)
+    callee = re.compile(r"\b(?:calls|to_apply|body|condition)=%([\w\.\-]+)")
+    todo = [n for n, b in blocks.items() if b.startswith("ENTRY")]
+    seen = set(todo)
+    while todo:
+        for name in callee.findall(blocks[todo.pop()]):
+            if name not in seen:
+                seen.add(name)
+                todo.append(name)
+    return seen
+
+
+def scope_blocks(hlo: str, scope: str) -> set[str]:
+    """Computations holding an op whose ``op_name`` names ``scope``."""
+    return {n for n, b in _blocks(hlo).items()
+            if re.search(r'op_name="[^"]*\b' + scope + r'/', b)}
+
+
 def _pool_copies(text: str, opname: str = "copy") -> dict[int, list[str]]:
     """{leading dim: lines} for pool-shaped results of ``opname``."""
     op = re.compile(r"=\s*(\([^)]*\)|[a-z0-9]+\[[^\]]*\](?:\{[^}]*\})?)\s+"
@@ -189,3 +211,16 @@ def test_hot_loop_contains_no_pool_sized_sort(hot_loop_hlo):
     assert not bad, (
         "pool-sized sort in the hot loop (full index rebuild leaked "
         "back):\n" + "\n".join(bad[:8]))
+
+
+@pytest.mark.parametrize("hlo", ["hot_loop_hlo", "quantized_hlo"])
+def test_scan_lane_runs_only_in_a_branch(hlo, request):
+    """The scan lane is kind-gated: every op of its scope sits in a
+    branch of a ``conditional``, none in what every step runs, so a
+    put/get/delete step does no scan work (and the budgets above show
+    the branch carries nothing pool-shaped)."""
+    text = request.getfixturevalue(hlo)
+    lane = scope_blocks(text, "scan_lane")
+    assert lane, "no op of the scan_lane scope in the compiled hot loop"
+    ungated = lane & every_step_blocks(text)
+    assert not ungated, sorted(ungated)

@@ -134,13 +134,9 @@ SCOPES = ("maintenance", "compact", "select", "demote", "merge",
           "point_ops", "tracker", "scan_lane", "consolidate", "obs_record")
 
 
-def test_engine_step_names_its_scopes_and_kernels(one_chip):
-    """The pallas engine step, compiled for the chip, keeps every device
-    scope in its ops' ``op_name`` metadata (a trace of the chip carries
-    only instruction names, which this metadata maps to scopes), its
-    program's name, and its kernels' names."""
-    import re
-
+@pytest.fixture(scope="module")
+def step_hlo(one_chip):
+    """The pallas engine step's compiled text for the described chip."""
     from repro.core import TierConfig, engine
     cfg = TierConfig(key_space=1 << 14, fast_slots=2048,
                      slow_slots=1 << 14, value_width=256, max_runs=64,
@@ -153,7 +149,16 @@ def test_engine_step_names_its_scopes_and_kernels(one_chip):
     op = one_chip(jax.eval_shape(lambda: engine.make_op(
         engine.PUT, jnp.zeros((256,), jnp.int32),
         value_width=cfg.value_width)))
-    text = engine.jit_step(ecfg).lower(state, op).compile().as_text()
+    return engine.jit_step(ecfg).lower(state, op).compile().as_text()
+
+
+def test_engine_step_names_its_scopes_and_kernels(step_hlo):
+    """The pallas engine step, compiled for the chip, keeps every device
+    scope in its ops' ``op_name`` metadata (a trace of the chip carries
+    only instruction names, which this metadata maps to scopes), its
+    program's name, and its kernels' names."""
+    import re
+    text = step_hlo
     assert text.startswith("HloModule jit_engine_step")
     stacks = {p for path in re.findall(r'op_name="([^"]*)"', text)
               for p in path.split("/")}
@@ -163,3 +168,14 @@ def test_engine_step_names_its_scopes_and_kernels(one_chip):
     assert kernels == {"clock_update", "msc_score",
                        "tier_compact_select_gather_rows",
                        "tier_compact_scatter_rows"}, kernels
+
+
+def test_engine_step_gates_its_scan_lane(step_hlo):
+    """Compiled for the chip, the scan lane's ops sit only in a branch of
+    a ``conditional`` on the batch's kind: a put/get/delete step runs
+    none of them."""
+    from tests.test_hlo_budget import every_step_blocks, scope_blocks
+    lane = scope_blocks(step_hlo, "scan_lane")
+    assert lane, "no op of the scan_lane scope in the compiled step"
+    ungated = lane & every_step_blocks(step_hlo)
+    assert not ungated, sorted(ungated)
