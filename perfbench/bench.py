@@ -1,7 +1,9 @@
 """Finds a cell's pieces by name: ``BENCHMARK.json`` at the checkout's
 root, ``configs/<config>.json``, ``traffic/<mix>.json``,
 ``metrics/<metric>.py`` and ``peaks.json``.  A cell added as data needs
-no edit here."""
+no edit here, nor in the harness: its configuration names the store
+(one ``PrismDB``, or ``partitions`` of a ``PartitionedDB``) and the cell
+its chips."""
 from __future__ import annotations
 
 import importlib.util

@@ -54,10 +54,15 @@ def main(argv=None) -> int:
         print("control: no TPU found", file=sys.stderr)
         return 3
     cell = bench.load_cell(args.workload)
+    devices = jax.devices()[:cell.chips]
+    if len(devices) < cell.chips:
+        print(f"control: the cell asks for {cell.chips} chips, JAX sees "
+              f"{len(devices)}", file=sys.stderr)
+        return 3
     todo = [("program", int(s)) for s in args.seeds.split(",") if s] + \
         [("control", int(s)) for s in args.control_seeds.split(",") if s]
     for kind, seed in todo:
-        db = harness.build_store(cell.config)
+        db = harness.build_store(cell.config, devices)
         store = LaggedPuts(db) if kind == "control" else db
         run, check = harness.run_cell(cell, seed, args.seconds, False,
                                       time.perf_counter(), store=store)

@@ -1,12 +1,30 @@
 """One run of one cell: set-up, the measured window, the comparison with
 the reference, and the metrics.
 
-The window drives the store's client facade (``PrismDB.put`` / ``get``)
-in a closed loop that keeps ``inflight`` batches outstanding: the next
-batch is submitted before the oldest one's result is fetched, since the
-facade's dispatch is asynchronous.  An op's latency runs from the start
-of its batch's facade call to that batch's result being on the host: a
-get's values copied back, a put's step finished on the device.
+The store is built from the configuration file: one ``PrismDB``, or
+with a top-level ``"partitions": P`` above 1 a ``PartitionedDB`` of P
+partitions, each with the pools ``tier`` describes, over the cell's
+chips (``build_store``).  ``tier.key_space`` is the whole key space in
+both cases: the traffic draws its keys from it.
+
+The window drives the store's client facade in a closed loop that keeps
+``inflight`` batches outstanding: the next batch is submitted before the
+oldest one's result is fetched, since the facade's dispatch is
+asynchronous.  An op's latency runs from the start of its batch's facade
+call to that batch's result being on the host: a get's values copied
+back, a put's step finished on the device.
+
+The facade contract: the harness calls only these, on either facade.
+- ``put(keys, vals)``: write row ``vals[i]`` under ``keys[i]``;
+- ``get(keys) -> (vals, found, src)``, the answers in the batch's order;
+- ``estate``, whose ``steps`` leaf is done once the last put's step is;
+- ``counters``: a dict of counters, with a leading partition axis on a
+  partitioned store (summed here into the store's totals);
+- optionally ``dropped``: the keys a routed batch could not place, so
+  far.  Each is a lost write or a lost answer, and fails the run.
+``PrismDB`` meets it.  ``PartitionedDB`` does not yet: its ``put(keys)``
+stores no payload, and its ``get`` answers in partition layout
+(``[P, per_part]``), not in the batch's order.
 """
 from __future__ import annotations
 
@@ -23,15 +41,54 @@ SAMPLE_ROWS = 16        # full rows compared per get batch, drawn from the seed
 TRACE_SECONDS = 4.0     # the traced window of a --trace 1 run, at most
 
 
-def build_store(config: dict):
-    """The system under test, as the configuration file states it."""
+def partitions(config: dict) -> int:
+    """The configuration's partition count: ``partitions``, default 1."""
+    p = config.get("partitions", 1)
+    if isinstance(p, bool) or not isinstance(p, int) or p < 1:
+        raise ValueError(f"partitions must be a whole number >= 1, got {p!r}")
+    return p
+
+
+def build_store(config: dict, devices=None):
+    """The system under test, as the configuration file states it: a
+    ``PrismDB`` on the default device, or with ``partitions`` P > 1 a
+    ``PartitionedDB`` over ``devices`` (None: the default device), on a
+    1-D ``part`` mesh where there is more than one and on the vmapped
+    path where there is one."""
     from repro.core import PrismDB, TierConfig, policy
     from repro.obs.state import ObsConfig
-    return PrismDB(TierConfig(**config["tier"]),
-                   seed=int(config["engine_seed"]),
-                   pol_cfg=policy.PolicyConfig(**config["policy"]),
-                   backend=config["backend"],
-                   obs=ObsConfig(**config["obs"]))
+    p = partitions(config)
+    n_dev = 1 if devices is None else len(devices)
+    if p % n_dev:
+        raise ValueError(f"{p} partitions cannot be spread evenly over "
+                         f"{n_dev} devices")
+    kwargs = dict(seed=int(config["engine_seed"]),
+                  pol_cfg=policy.PolicyConfig(**config["policy"]),
+                  backend=config["backend"], obs=ObsConfig(**config["obs"]))
+    if p == 1:
+        return PrismDB(TierConfig(**config["tier"]), **kwargs)
+    import jax
+    from repro.core import PartitionedDB
+    from repro.core.db import PART_AXIS
+    mesh = (jax.sharding.Mesh(np.asarray(devices), (PART_AXIS,))
+            if n_dev > 1 else None)
+    return PartitionedDB(TierConfig(**config["tier"]), n_partitions=p,
+                         mesh=mesh, **kwargs)
+
+
+def store_totals(counters: dict) -> dict:
+    """A partitioned store's counters summed over their leading partition
+    axis, vectors element by element: the values a one-partition store
+    of the same pools would report."""
+    return {k: np.asarray(v).sum(axis=0).tolist()
+            for k, v in counters.items()}
+
+
+def memory_peak_bytes(devices) -> int:
+    """The largest ``peak_bytes_in_use`` over ``devices``: the fullest
+    chip's peak."""
+    return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+               for d in devices)
 
 
 @dataclass
@@ -56,11 +113,11 @@ class Run:
     ops: int = 0
     puts: int = 0
     gets: int = 0
-    counters0: dict = field(default_factory=dict)
+    counters0: dict = field(default_factory=dict)   # the store's totals
     counters1: dict = field(default_factory=dict)
     window_compiles: int = 0        # programs compiled inside the window
     trace: dict | None = None       # tracing.reduce's output
-    memory_peak_bytes: int | None = None
+    memory_peak_bytes: int | None = None   # of the fullest of the chips
 
     @property
     def window_s(self) -> float:
@@ -221,21 +278,25 @@ class Client:
 
 
 def run_cell(cell, seed: int, seconds: float, trace: bool, t_start: float,
-             store=None, device=None) -> tuple[Run, reference.Check]:
+             store=None, devices=None) -> tuple[Run, reference.Check]:
     """Set up, measure and check one run.  ``store`` replaces the system
-    under test (the tests hand in a broken one); ``device`` is the JAX
-    device whose memory peak is read (None: not read)."""
+    under test (the tests hand in a broken one); ``devices`` are the JAX
+    devices of the cell's chips: the store is built over them, and the
+    fullest one's memory peak is read (None: the default device, and no
+    peak read)."""
     import jax
     from perfbench import tracing
     cfg = cell.config
     run = Run()
-    db = store if store is not None else build_store(cfg)
+    db = store if store is not None else build_store(cfg, devices)
+    partitioned = partitions(cfg) > 1
+    dropped0 = getattr(db, "dropped", None)
     client = Client(db, cell.traffic, int(cfg["tier"]["key_space"]),
                     int(cfg["tier"]["value_width"]), seed, spans=trace)
     client.load()
     client.warm(int(cell.traffic["warmup_batches"]))
     jax.block_until_ready(db.estate)
-    run.counters0 = db.counters
+    run.counters0 = store_totals(db.counters) if partitioned else db.counters
     run.setup_s = time.perf_counter() - t_start
 
     seconds = min(seconds, TRACE_SECONDS) if trace else seconds
@@ -246,11 +307,12 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t_start: float,
     with tracing.capture(trace) as captured:
         client.window(run, seconds)
     run.window_compiles = len(compiles)
-    run.counters1 = db.counters
-    if device is not None:
-        run.memory_peak_bytes = int(
-            (device.memory_stats() or {}).get("peak_bytes_in_use", 0))
+    run.counters1 = store_totals(db.counters) if partitioned else db.counters
+    if devices is not None:
+        run.memory_peak_bytes = memory_peak_bytes(devices)
     if trace:
         run.trace = tracing.reduce_file(captured)
     client.readback()
+    if dropped0 is not None:
+        client.check.dropped = int(db.dropped) - int(dropped0)
     return run, client.check

@@ -1,4 +1,5 @@
-"""Host time of one facade call (``PrismDB.put`` / ``get``): building the
+"""Host time of one facade call (``put`` / ``get`` of ``PrismDB``, or of
+``PartitionedDB`` for a configuration with ``partitions``): building the
 op, copying it to the device and enqueueing the step, per batch."""
 
 

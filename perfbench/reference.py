@@ -55,11 +55,15 @@ class Check:
     ``stale_or_lost``: get answers (every lane of every get batch) that
     were not found, or whose key or version differs from the reference.
     ``bad_rows``: rows of a seeded sample of each get batch, and every
-    read-back row, with any lane unlike the reference's row."""
+    read-back row, with any lane unlike the reference's row.
+    ``dropped``: keys that a store which routes its batches could not
+    place, over set-up, window and read-back (None: the store does not
+    route, and the number is not compared)."""
     answers: int = 0
     stale_or_lost: int = 0
     rows_compared: int = 0
     bad_rows: int = 0
+    dropped: int | None = None
     examples: list = field(default_factory=list)
 
     def compare(self, keys, want_version, found, head, sample_idx,
@@ -84,10 +88,18 @@ class Check:
 
     def numbers(self) -> dict:
         """Each number compared, with its limit: an exact comparison."""
-        return {"stale_or_lost": {"value": self.stale_or_lost, "limit": 0},
-                "bad_rows": {"value": self.bad_rows, "limit": 0}}
+        out = {"stale_or_lost": {"value": self.stale_or_lost, "limit": 0},
+               "bad_rows": {"value": self.bad_rows, "limit": 0}}
+        if self.dropped is not None:
+            out["dropped"] = {"value": self.dropped, "limit": 0}
+        return out
+
+    @property
+    def failed(self) -> int:
+        """Answers and rows that came out wrong, and keys dropped."""
+        return self.stale_or_lost + self.bad_rows + (self.dropped or 0)
 
     @property
     def correct(self) -> bool:
         return (self.answers > 0 and self.rows_compared > 0
-                and self.stale_or_lost == 0 and self.bad_rows == 0)
+                and self.failed == 0)

@@ -44,11 +44,12 @@ def result_line(cell, run, check, dev, n_devices: int, trace: bool) -> dict:
         value = bench.metric_reader(m["name"])(run)
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    # memory_peak_bytes: the peak of the fullest of the cell's chips
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": n_devices,
               "memory_peak_bytes": run.memory_peak_bytes}
     out = {"correct": check.correct, "attempted": run.ops,
-           "failed": check.stale_or_lost + check.bad_rows,
+           "failed": check.failed,
            "metrics": metrics, "device": device}
     if trace:
         device["busy_s"] = run.trace["busy_s"]
@@ -86,7 +87,8 @@ def main(argv=None) -> int:
     bench.peaks(dev.device_kind)
 
     run, check = harness.run_cell(cell, args.seed, args.seconds,
-                                  bool(args.trace), T_START, device=dev)
+                                  bool(args.trace), T_START,
+                                  devices=devs[:cell.chips])
     out = result_line(cell, run, check, dev, cell.chips, bool(args.trace))
     print(f"window: {len(run.kinds)} batches, {run.ops} ops in "
           f"{run.window_s:.3f} s, {run.window_compiles} compiled in it; "

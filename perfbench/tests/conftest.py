@@ -21,7 +21,8 @@ TINY_TIER = {"key_space": 4096, "fast_slots": 512, "slow_slots": 4096,
 @pytest.fixture
 def tiny_root(tmp_path):
     """A checkout holding a copy of perfbench and a BENCHMARK.json with
-    one small cell, ``tiny-a``, made only of new data files."""
+    two small cells made only of new data files: ``tiny-a``, and
+    ``tiny-p4``, the same configuration as 4 partitions on 4 chips."""
     root = tmp_path / "checkout"
     shutil.copytree(PERFBENCH, root / "perfbench",
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
@@ -33,6 +34,8 @@ def tiny_root(tmp_path):
     config["tier"].update(TINY_TIER)
     (root / "perfbench" / "configs" / "tiny.json").write_text(
         json.dumps(config))
+    (root / "perfbench" / "configs" / "tiny-p4.json").write_text(
+        json.dumps(dict(config, name="tiny-p4", partitions=4)))
     with open(os.path.join(PERFBENCH, "traffic", "ycsb-a.json")) as f:
         mix = json.load(f)
     # a put batch must fit the fast tier (the store drops what does not)
@@ -50,9 +53,16 @@ def tiny_root(tmp_path):
         "name": "tiny", "source": "https://example.org/tiny",
         "file": "perfbench/configs/tiny.json", "reduced": ["recordcount"],
         "why": "a test cell"})
+    bench["configs"].append({
+        "name": "tiny-p4", "source": "https://example.org/tiny",
+        "file": "perfbench/configs/tiny-p4.json", "reduced": ["recordcount"],
+        "why": "a partitioned test cell"})
     bench["workloads"].append({
         "name": "tiny-a", "config": "tiny", "traffic": "tiny-a",
         "chips": 1, "why": "a test cell"})
+    bench["workloads"].append({
+        "name": "tiny-p4", "config": "tiny-p4", "traffic": "tiny-a",
+        "chips": 4, "why": "a partitioned test cell"})
     bench["per_layer"].append({
         "name": "gets_per_put", "unit": "1", "better": "higher",
         "source": "host_clock", "layer": "facade", "moves": "ops_s",
